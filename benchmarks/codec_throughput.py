@@ -46,6 +46,7 @@ from repro.core.codecs import (CODECS, Codec, PackedBitstreamCodec,
 from repro.core.compression import (expected_pytree_wire_bytes,
                                     pytree_dense_bytes)
 from repro.models.cnn import init_cnn
+from repro.launch.cache import enable_compile_cache
 
 RESULTS_PATH = os.path.join(os.path.dirname(__file__), "..", "results",
                             "codec_throughput.json")
@@ -174,6 +175,7 @@ def main() -> None:
                          "logical XLA devices")
     args = ap.parse_args()
     maybe_reexec_host_tuned(args.host_tuning, args.host_devices)
+    enable_compile_cache()
     run(reps=args.reps, out_path=args.out)
 
 

@@ -49,18 +49,30 @@ def maybe_reexec_host_tuned(enable: bool, host_devices: int = 0) -> bool:
     ``LD_PRELOAD`` tcmalloc (a loader setting — it cannot be enabled from
     inside a running process, hence the ``os.execve``) and, when
     ``host_devices > 0``, ``XLA_FLAGS=--xla_force_host_platform_device_count``
-    so XLA partitions the host CPU into that many logical devices (must be
-    set before jax initializes — the re-exec'd process imports jax fresh).
+    so XLA partitions the host CPU into that many logical devices.
 
-    Call this as early as possible in a benchmark ``main()``.  Returns
-    ``False`` when tuning is disabled or already applied (the re-exec'd
-    process carries the ``_REPRO_HOST_TUNED`` marker, which both prevents an
-    exec loop and tells the benchmark the run is host-tuned); on success the
-    call does not return at all."""
+    Call this first thing in a benchmark ``main()``: it raises if a JAX
+    backend is already initialized (the process would re-exec while holding
+    the accelerator, and the flag could no longer take effect), and the
+    re-exec'd process refuses ``host_devices > 0`` when its backend is a
+    TPU, where host devices have no meaning.  Returns ``False`` when tuning
+    is disabled or already applied (the re-exec'd process carries the
+    ``_REPRO_HOST_TUNED`` marker, which both prevents an exec loop and tells
+    the benchmark the run is host-tuned); on success the call does not
+    return at all."""
     if os.environ.get(_HOST_TUNED_MARKER):
+        if host_devices > 0:
+            import jax
+            if jax.default_backend() == "tpu":
+                raise ValueError("--host-devices partitions the host CPU; "
+                                 "it has no meaning on a TPU backend")
         return False
     if not enable:
         return False
+    from jax._src import xla_bridge
+    if xla_bridge.backends_are_initialized():
+        raise RuntimeError("maybe_reexec_host_tuned must run before any JAX "
+                           "backend is initialized")
     env = dict(os.environ, **{_HOST_TUNED_MARKER: "1"})
     for path in TCMALLOC_PATHS:
         if os.path.exists(path):

@@ -65,6 +65,7 @@ from repro.data.synthetic import partition_iid
 from repro.fl.protocols import make_sim
 from repro.fl.simulator import ScenarioConfig, SimConfig, TierSpec
 from repro.fl.tasks import TASKS, get_task
+from repro.launch.cache import enable_compile_cache
 
 RESULTS_PATH = os.path.join(os.path.dirname(__file__), "..", "results",
                             "engine_scale.json")
@@ -307,6 +308,7 @@ def main():
                          "cost ratio under fmnist_mlp's 'dispatch' key")
     args = ap.parse_args()
     maybe_reexec_host_tuned(args.host_tuning, args.host_devices)
+    enable_compile_cache()
 
     if args.fleet:
         runs = {}

@@ -2,6 +2,7 @@
 time-to-target), IID and non-IID, vs FedAvg / FedAsync baselines."""
 from benchmarks.common import (Scale, print_csv, record,
                                scale_from_args, simulate, std_argparser)
+from repro.launch.cache import enable_compile_cache
 
 CS = [0.05, 0.1, 0.3]
 
@@ -21,6 +22,7 @@ def run(scale: Scale):
 
 def main():
     args = std_argparser(__doc__).parse_args()
+    enable_compile_cache()
     print_csv("fig3_5_c", run(scale_from_args(args)))
 
 

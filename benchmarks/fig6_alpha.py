@@ -1,6 +1,7 @@
 """Fig. 6: robustness to the mixing hyper-parameter alpha."""
 from benchmarks.common import (Scale, print_csv, record,
                                scale_from_args, simulate, std_argparser)
+from repro.launch.cache import enable_compile_cache
 
 ALPHAS = [0.2, 0.6, 0.9]
 
@@ -18,6 +19,7 @@ def run(scale: Scale):
 
 def main():
     args = std_argparser(__doc__).parse_args()
+    enable_compile_cache()
     print_csv("fig6_alpha", run(scale_from_args(args)))
 
 

@@ -3,6 +3,7 @@
 from benchmarks.common import (Scale, compression_points, print_csv,
                                record, scale_from_args, simulate,
                                std_argparser)
+from repro.launch.cache import enable_compile_cache
 
 
 def run(scale: Scale):
@@ -19,6 +20,7 @@ def run(scale: Scale):
 
 def main():
     args = std_argparser(__doc__).parse_args()
+    enable_compile_cache()
     print_csv("fig8_ablation", run(scale_from_args(args)))
 
 

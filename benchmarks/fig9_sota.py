@@ -4,6 +4,7 @@ simplified baseline implementations."""
 from benchmarks.common import (Scale, compression_points, print_csv,
                                record, scale_from_args, simulate,
                                std_argparser)
+from repro.launch.cache import enable_compile_cache
 
 
 def run(scale: Scale):
@@ -22,6 +23,7 @@ def run(scale: Scale):
 
 def main():
     args = std_argparser(__doc__).parse_args()
+    enable_compile_cache()
     print_csv("fig9_sota", run(scale_from_args(args)))
 
 

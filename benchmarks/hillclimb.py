@@ -17,6 +17,8 @@ import json
 import os
 import sys
 
+from repro.launch.cache import enable_compile_cache
+
 VARIANTS = {
     # pair C (and A): fed-exchange schedule ladder, + memory lever
     "C": [
@@ -67,6 +69,7 @@ def main() -> None:
     ap.add_argument("--pair", required=True, choices=["A", "B", "C"])
     ap.add_argument("--out", default="results/perf/hillclimb.json")
     args = ap.parse_args()
+    enable_compile_cache()
 
     from repro.launch.dryrun import run_one  # sets XLA_FLAGS on import
 

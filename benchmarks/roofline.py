@@ -2,9 +2,12 @@
 
 For each (arch x shape x mesh) record in results/dryrun_*.json:
 
-  compute term    = HLO_FLOPs/device   / 197e12   (TPU v5e bf16 peak)
-  memory term     = HLO_bytes/device   / 819e9    (HBM bandwidth)
-  collective term = coll_bytes/device  / 50e9     (ICI link bandwidth)
+  compute term    = HLO_FLOPs/device   / peak bf16 FLOP/s
+  memory term     = HLO_bytes/device   / peak HBM bytes/s
+  collective term = coll_bytes/device  / ICI bytes/s per link
+
+with the peaks of the chip the meshes model (``PEAKS``, keyed by JAX's
+``device_kind``; the dry run's meshes are v5e pods).
 
 HLO_FLOPs and HLO_bytes come from compiled.cost_analysis() (per-partition
 module); collective bytes from the trip-count-aware HLO parser in
@@ -21,9 +24,24 @@ import os
 import sys
 from typing import Dict, List, Optional
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+# Published per-chip peaks keyed by ``jax.devices()[0].device_kind``.
+# Source: Google Cloud documentation, "TPU v5e" (system architecture):
+# 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s of inter-chip
+# interconnect over the chip's 4 ICI links.
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9,
+                    "ici_link_bytes_per_s": 1600e9 / 8 / 4},
+}
+DRYRUN_DEVICE_KIND = "TPU v5 lite"      # the chip the dry-run meshes model
+
+
+def peaks_for(device_kind: str) -> Dict[str, float]:
+    """Peaks of one chip; a kind with no published entry is an error."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 SHAPES_TOKENS = {
     "train_4k": 4096 * 256,
@@ -43,7 +61,8 @@ def model_flops(rec: Dict) -> float:
     return float(mult * n * toks)
 
 
-def analyze(rec: Dict, chips: int) -> Optional[Dict]:
+def analyze(rec: Dict, chips: int, device_kind: str) -> Optional[Dict]:
+    peaks = peaks_for(device_kind)
     if "cost" not in rec or "collectives" not in rec:
         return None
     # prefer the trip-count-aware estimates (XLA cost_analysis counts while
@@ -53,15 +72,16 @@ def analyze(rec: Dict, chips: int) -> Optional[Dict]:
     bytes_dev = rec["cost"].get("bytes_trip_aware") or \
         rec["cost"].get("bytes accessed", 0.0)
     coll_dev = rec["collectives"].get("total", 0.0)
-    t_c = flops_dev / PEAK_FLOPS
-    t_m = bytes_dev / HBM_BW
-    t_n = coll_dev / ICI_BW
+    t_c = flops_dev / peaks["bf16_flops"]
+    t_m = bytes_dev / peaks["hbm_bytes_per_s"]
+    t_n = coll_dev / peaks["ici_link_bytes_per_s"]
     terms = {"compute": t_c, "memory": t_m, "collective": t_n}
     dominant = max(terms, key=terms.get)
     mf = model_flops(rec)
     ratio = mf / max(flops_dev * chips, 1.0)
     bound = max(terms.values())
-    mfu_bound = (mf / chips / PEAK_FLOPS) / bound if bound > 0 else 0.0
+    mfu_bound = ((mf / chips / peaks["bf16_flops"]) / bound if bound > 0
+                 else 0.0)
     return {
         "arch": rec["arch"], "shape": rec["shape"], "mesh": rec["mesh"],
         "step": rec.get("step"),
@@ -121,7 +141,7 @@ def main() -> None:
         if "error" in rec:
             continue
         chips = 512 if rec["mesh"] == "2x16x16" else 256
-        row = analyze(rec, chips)
+        row = analyze(rec, chips, DRYRUN_DEVICE_KIND)
         if row:
             row["advice"] = advice(row)
             rows.append(row)

@@ -15,6 +15,7 @@ from benchmarks import (engine_scale, fig2_mu, fig3_c_fraction, fig6_alpha,
                         fig8_ablation, fig9_sota, table3_6_compression,
                         table7_sizes)
 from benchmarks.common import Scale, print_csv
+from repro.launch.cache import enable_compile_cache
 
 SUITES = {
     "fig2": (fig2_mu, "fig2_mu"),
@@ -34,6 +35,7 @@ def main() -> None:
     ap.add_argument("--only", default="",
                     help="comma-separated subset of " + ",".join(SUITES))
     args = ap.parse_args()
+    enable_compile_cache()
 
     # engine_scale is a wall-clock race at N=1000 — opt-in via --only
     names = [n.strip() for n in args.only.split(",") if n.strip()] or \
@@ -69,7 +71,7 @@ def main() -> None:
         for rec in rows:
             if "error" in rec:
                 continue
-            r = roofline.analyze(rec, 256)
+            r = roofline.analyze(rec, 256, roofline.DRYRUN_DEVICE_KIND)
             if r:
                 dom_s = max(r["compute_s"], r["memory_s"], r["collective_s"])
                 print(f"roofline/{r['arch']}_{r['shape']},{dom_s*1e6:.1f},"

@@ -38,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.fl.tasks import get_task
+from repro.launch.cache import enable_compile_cache
 from repro.launch.serve import ContinuousBatcher, generate
 
 RESULTS_PATH = os.path.join(os.path.dirname(__file__), "..", "results",
@@ -148,6 +149,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default=RESULTS_PATH)
     args = ap.parse_args()
+    enable_compile_cache()
     run(batch=args.batch, requests=args.requests, prompt_len=args.prompt_len,
         gen=args.gen, task=args.task, seed=args.seed, out_path=args.out)
 

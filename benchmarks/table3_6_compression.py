@@ -4,6 +4,7 @@ non-IID."""
 from benchmarks.common import (Scale, best_acc_within, compression_points,
                                print_csv, record, simulate, std_argparser,
                                time_to_acc)
+from repro.launch.cache import enable_compile_cache
 
 BUDGET_FRACS = [1 / 6, 1 / 3, 1 / 2, 2 / 3, 5 / 6, 1.0]
 
@@ -37,6 +38,7 @@ def run(scale: Scale):
 
 def main():
     args = std_argparser(__doc__).parse_args()
+    enable_compile_cache()
     rows = run(Scale(args.full))
     print_csv("table3_6", rows)
     for r in rows:

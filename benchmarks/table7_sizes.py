@@ -1,6 +1,7 @@
 """Table 7: maximum transmitted model size per method (wire bytes)."""
 from benchmarks.common import (Scale, compression_points, record,
                                simulate, std_argparser)
+from repro.launch.cache import enable_compile_cache
 
 
 def run(scale: Scale):
@@ -24,6 +25,7 @@ def run(scale: Scale):
 
 def main():
     args = std_argparser(__doc__).parse_args()
+    enable_compile_cache()
     rows = run(Scale(args.full))
     for r in rows:
         tag = "iid" if r["iid"] else "noniid"

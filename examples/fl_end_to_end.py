@@ -40,6 +40,7 @@ from repro.fl.protocols import (best_acc_within, make_setup,
                                 profile_compression, run_method)
 from repro.fl.simulator import ScenarioConfig, SimConfig, TierSpec
 from repro.fl.tasks import TASKS
+from repro.launch.cache import enable_compile_cache
 
 
 def run_fleet_demo(args) -> None:
@@ -145,6 +146,7 @@ def main():
                          "(repro.fl.fleet.ASSIGNERS); only used with "
                          "--fleet (default: %(default)s)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.fleet:
         run_fleet_demo(args)

@@ -18,11 +18,12 @@ import numpy as np
 
 from repro.configs.base import get_smoke_config
 from repro.core.fed_step import FedConfig, fed_wire_bytes, make_fed_train_step
+from repro.launch.mesh import make_host_mesh
 from repro.models import transformer as T
 from repro.sharding.rules import Rules, use_rules
 
 cfg = get_smoke_config("smollm-135m")
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_host_mesh(data=4, model=2)
 rules = Rules(mesh)
 
 params = T.init_model(jax.random.PRNGKey(0), cfg)

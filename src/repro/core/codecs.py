@@ -231,8 +231,8 @@ class PackedBitstreamCodec(Codec):
     **Fused fast path**: with ``fused=True`` (the default), deterministic
     encodes (``rng is None``) go through the one-pass fused emitter
     ``repro.kernels.ops.fused_wire_encode`` — the ``fused_pack`` Pallas
-    kernel on TPU (REPRO_PALLAS_NATIVE=1), its vectorized numpy twin on
-    host — which writes the packed words directly at dense-codec speed.
+    kernel where JAX runs on a TPU, its vectorized numpy twin on the CPU
+    backend — which writes the packed words directly at dense-codec speed.
     Stochastic (rng) encodes always take the multi-pass ``compress_tensor``
     pipeline: engines pass the shared sim RNG, so protocol histories keep
     the exact legacy draw order regardless of ``fused``.  ``fused=False``

@@ -10,10 +10,10 @@ one-subclass-plus-one-entry idiom as STRATEGIES / CODECS / SCHEDULERS):
 * ``"single"`` — :class:`TeasqServer`, the bit-pinned single-host
   reference every history fixture was recorded against.
 * ``"sharded"`` — :class:`ShardedTeasqServer`, which partitions the
-  flattened weight vector across a 1-D device mesh (host devices under
-  ``XLA_FLAGS=--xla_force_host_platform_device_count=N``) and runs the
-  stacked Eqs. 6-10 reduction as a ``shard_map``; with one device it
-  degenerates to the parent's exact path.
+  flattened weight vector across a 1-D device mesh (chips, or host
+  devices under ``XLA_FLAGS=--xla_force_host_platform_device_count=N``)
+  and runs the stacked Eqs. 6-10 reduction as a ``shard_map``; with one
+  device it degenerates to the parent's exact path.
 
 ``SimConfig.server`` selects the backend; ``make_server`` resolves it.
 """
@@ -117,8 +117,8 @@ class ShardedTeasqServer(TeasqServer):
     mesh (the "Sharded aggregation" ROADMAP tentpole).
 
     The flattened weight vector is partitioned into equal column blocks
-    across a 1-D mesh of the first ``n_shards`` local jax devices (host
-    devices when the process runs under
+    across a 1-D mesh of the first ``n_shards`` local jax devices (chips,
+    or host devices when the process runs under
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N``), and both the
     serial and the wave receive paths reduce through ONE
     ``shard_map``-compiled flat kernel (``make_sharded_aggregator``).
@@ -127,8 +127,9 @@ class ShardedTeasqServer(TeasqServer):
     ``aggregate_cache_stacked`` to <= 1 ulp (tests/test_sharded_server.py
     pins this across mesh sizes).
 
-    With ``n_shards`` resolving to 1 (the default single-device process)
-    no mesh is built and BOTH paths delegate to the parent's kernels
+    Asking for more shards than the process has devices raises.  With
+    ``n_shards`` resolving to 1 (the default single-device process) no
+    mesh is built and BOTH paths delegate to the parent's kernels
     unchanged — the degenerate server is bit-identical to
     :class:`TeasqServer`, so the pinned history fixtures stay valid under
     ``SimConfig.server="sharded"`` on one device."""
@@ -139,8 +140,10 @@ class ShardedTeasqServer(TeasqServer):
         import numpy as np
         from jax.sharding import Mesh
         devs = jax.devices()
-        want = int(n_shards) if n_shards > 0 else len(devs)
-        self.n_shards = max(1, min(want, len(devs)))
+        self.n_shards = int(n_shards) if n_shards > 0 else len(devs)
+        if self.n_shards > len(devs):
+            raise ValueError(f"server_shards={self.n_shards} needs as many "
+                             f"devices, this process has {len(devs)}")
         self.mesh = None
         self._agg = None
         if self.n_shards > 1:
@@ -170,8 +173,9 @@ SERVERS: Dict[str, type] = {
 def make_server(name: str, w_init: Any, cfg: ServerConfig, *,
                 shards: int = 0) -> TeasqServer:
     """Resolve ``SimConfig.server`` to a constructed server backend.
-    ``shards`` (``SimConfig.server_shards``) caps the mesh width for
-    sharded backends: 0 means "all local devices"."""
+    ``shards`` (``SimConfig.server_shards``) is the mesh width for
+    sharded backends: 0 means "all local devices"; more than the process
+    has raises."""
     try:
         cls = SERVERS[name]
     except KeyError:

@@ -250,8 +250,8 @@ class SimConfig:
       ``XLA_FLAGS=--xla_force_host_platform_device_count=N``; on a
       single-device process it degenerates to the exact ``"single"``
       path).  The legacy ``FLSimulator`` ignores it.
-    * ``server_shards`` — mesh width cap for ``server="sharded"``
-      (0 = use every local device).
+    * ``server_shards`` — mesh width for ``server="sharded"`` (0 = use
+      every local device; more than the process has raises).
     * ``scenario`` — ``ScenarioConfig`` injection (dropout / transient
       failure / heterogeneity tiers); see its docstring for which backend
       consumes what.

@@ -1,23 +1,19 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-``interpret=True`` everywhere by default: this container is CPU-only, so the
-kernels execute their bodies in Python (bit-accurate) while targeting TPU
-``pallas_call`` + BlockSpec lowering.  On real TPU hardware pass
-``interpret=False`` (or set REPRO_PALLAS_NATIVE=1).
+Where a kernel runs follows the default JAX backend
+(``repro.kernels.backend.resolve_interpret``): natively lowered on a TPU,
+under the Pallas interpreter (bit-accurate, same kernel body) only on the
+CPU backend.  ``interpret=True``/``False`` forces one or the other.
 """
 from __future__ import annotations
 
-import os
-from typing import Any, Optional, Tuple
+from typing import Any, Optional
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels import fused_pack, ref
 from repro.kernels.ssd_scan import ssd_chunked_pallas
 from repro.kernels.topk_quant import DEFAULT_BLOCK, dequant, topk_quant
-
-_NATIVE = bool(int(os.environ.get("REPRO_PALLAS_NATIVE", "0")))
 
 
 def fused_wire_encode(tree: Any, p_s: float, p_q: int,
@@ -28,17 +24,17 @@ def fused_wire_encode(tree: Any, p_s: float, p_q: int,
     deterministic rounding; ``len(result) == expected_pytree_wire_bytes``.
 
     ``backend``:
-      * ``None`` — auto: the native Pallas kernel when REPRO_PALLAS_NATIVE=1
-        (real TPU), otherwise the vectorized numpy twin (on CPU the twin is
-        the fast path — per-leaf pallas_call dispatch costs ~ms on host,
-        the same trade ``bitpack`` makes for its jnp kernels);
+      * ``None`` — auto: the vectorized numpy twin on the CPU backend (there
+        the twin is the fast path — per-leaf pallas_call dispatch costs ~ms
+        on host, the same trade ``bitpack`` makes for its jnp kernels), the
+        native Pallas kernel on any other backend;
       * ``"host"`` — force the numpy twin;
       * ``"interpret"`` — force the Pallas kernel under the interpreter
         (bit-accurate kernel body on CPU; what CI exercises);
       * ``"native"`` — force real TPU lowering.
     """
     if backend is None:
-        backend = "native" if _NATIVE else "host"
+        backend = "host" if jax.default_backend() == "cpu" else "native"
     leaves = jax.tree.leaves(tree)
     if backend == "host":
         return fused_pack.pack_leaves_host(leaves, p_s, p_q)
@@ -50,20 +46,16 @@ def fused_wire_encode(tree: Any, p_s: float, p_q: int,
 
 def compress_roundtrip(x: jax.Array, p_s: float = 0.25, bits: int = 8,
                        block: int = DEFAULT_BLOCK,
-                       interpret: bool = None) -> jax.Array:
+                       interpret: Optional[bool] = None) -> jax.Array:
     """Kernel-backed lossy compress->decompress of an arbitrary tensor."""
-    if interpret is None:
-        interpret = not _NATIVE
     levels, scales = topk_quant(x.reshape(-1), p_s=p_s, bits=bits,
                                 block=block, interpret=interpret)
     return dequant(levels, scales, bits, x.size, x.shape).astype(x.dtype)
 
 
 def ssd(xh, b, c, dt, la, chunk: int, use_pallas: bool = True,
-        interpret: bool = None):
+        interpret: Optional[bool] = None):
     """Mamba2 SSD: kernel-backed or pure-jnp reference."""
-    if interpret is None:
-        interpret = not _NATIVE
     if use_pallas:
         return ssd_chunked_pallas(xh, b, c, dt, la, chunk,
                                   interpret=interpret)

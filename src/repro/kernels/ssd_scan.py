@@ -12,69 +12,79 @@ a jax.lax.scan around the kernel — it is O(nc * N * P) and bandwidth-trivial.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.backend import resolve_interpret
 
-def _kernel(xb_ref, b_ref, c_ref, cum_ref, y_ref, s_ref, a_ref):
+
+def _kernel(xb_ref, b_ref, c_ref, row_ref, col_ref, y_ref, s_ref):
     xb = xb_ref[0].astype(jnp.float32)              # (L, P)
     b = b_ref[0].astype(jnp.float32)                # (L, N)
     c = c_ref[0].astype(jnp.float32)                # (L, N)
-    cum = cum_ref[0].astype(jnp.float32)            # (1, L) row vector
-    cum = cum[0]                                    # (L,)
+    # cumulative log-decay twice, as a (1, L) row and an (L, 1) column:
+    # the kernel then needs no in-register transpose
+    row = row_ref[0].astype(jnp.float32)            # (1, L)
+    col = col_ref[0].astype(jnp.float32)            # (L, 1)
     L_ = xb.shape[0]
 
     cb = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())))   # (L, L)
     ii = jax.lax.broadcasted_iota(jnp.int32, (L_, L_), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (L_, L_), 1)
     # mask the exponent (upper triangle overflows exp -> inf -> nan grads)
-    diff = jnp.where(ii >= jj, cum[:, None] - cum[None, :], -jnp.inf)
+    diff = jnp.where(ii >= jj, col - row, -jnp.inf)
     m = jnp.exp(diff)
     y = (cb * m) @ xb                                          # (L, P)
 
-    d2e = jnp.exp(cum[-1] - cum)                               # (L,)
-    s = jax.lax.dot_general(b * d2e[:, None], xb,
+    d2e = jnp.exp(col[L_ - 1:, :] - col)                       # (L, 1)
+    s = jax.lax.dot_general(b * d2e, xb,
                             (((0,), (0,)), ((), ())))          # (N, P)
     y_ref[0] = y.astype(y_ref.dtype)
     s_ref[0] = s.astype(s_ref.dtype)
-    a_ref[...] = jnp.exp(cum[-1]).reshape(1, 1)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
 def ssd_intra_chunk(xb: jax.Array, b: jax.Array, c: jax.Array,
-                    cum: jax.Array, *, interpret: bool = True
+                    cum: jax.Array, *, interpret: Optional[bool] = None
                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Batched intra-chunk SSD.
 
     xb: (G, L, P) dt-scaled inputs (G = B*H*nc grid cells)
     b, c: (G, L, N); cum: (G, 1, L) cumulative log-decay.
     -> (y (G,L,P) f32, states (G,N,P) f32, chunk_decay (G,1) f32)
+    ``interpret=None`` runs the Pallas interpreter on the CPU backend and
+    the native kernel elsewhere.
     """
+    return _ssd_intra_chunk_call(xb, b, c, cum,
+                                 interpret=resolve_interpret(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _ssd_intra_chunk_call(xb, b, c, cum, *, interpret: bool):
     G, L, P = xb.shape
     N = b.shape[-1]
-    y, s, a = pl.pallas_call(
+    y, s = pl.pallas_call(
         _kernel,
         grid=(G,),
         in_specs=[pl.BlockSpec((1, L, P), lambda i: (i, 0, 0)),
                   pl.BlockSpec((1, L, N), lambda i: (i, 0, 0)),
                   pl.BlockSpec((1, L, N), lambda i: (i, 0, 0)),
-                  pl.BlockSpec((1, 1, L), lambda i: (i, 0, 0))],
+                  pl.BlockSpec((1, 1, L), lambda i: (i, 0, 0)),
+                  pl.BlockSpec((1, L, 1), lambda i: (i, 0, 0))],
         out_specs=[pl.BlockSpec((1, L, P), lambda i: (i, 0, 0)),
-                   pl.BlockSpec((1, N, P), lambda i: (i, 0, 0)),
-                   pl.BlockSpec((1, 1), lambda i: (i, 0))],
+                   pl.BlockSpec((1, N, P), lambda i: (i, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct((G, L, P), jnp.float32),
-                   jax.ShapeDtypeStruct((G, N, P), jnp.float32),
-                   jax.ShapeDtypeStruct((G, 1), jnp.float32)],
+                   jax.ShapeDtypeStruct((G, N, P), jnp.float32)],
         interpret=interpret,
-    )(xb, b, c, cum)
-    return y, s, a
+    )(xb, b, c, cum, jnp.swapaxes(cum, 1, 2))
+    # the chunk decay is one exp per cell: XLA, not a (1, 1) kernel block
+    return y, s, jnp.exp(cum[:, 0, -1:])
 
 
 def ssd_chunked_pallas(xh, b, c, dt, la, chunk: int, *,
-                       interpret: bool = True):
+                       interpret: Optional[bool] = None):
     """Drop-in replacement for models.ssm.ssd_chunked using the kernel for
     the intra-chunk quadratic part.  Shapes as in ssd_chunked."""
     B, S, H, P = xh.shape

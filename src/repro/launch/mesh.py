@@ -7,24 +7,30 @@ import; smoke tests and benchmarks see the real single CPU device.
 """
 from __future__ import annotations
 
+from typing import Sequence
+
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def make_auto_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
+    """``jax.make_mesh`` with every axis Auto: the models place activations
+    with ``with_sharding_constraint`` (``repro.sharding.rules.shard``),
+    which only accepts Auto axes, while ``jax.make_mesh`` defaults to
+    Explicit ones."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> Mesh:
-    """Small mesh over however many (CPU) devices exist — for tests."""
+    """Small (data, model) mesh over the local devices."""
     n = len(jax.devices())
-    assert data * model <= n, f"need {data * model} devices, have {n}"
-    return jax.make_mesh((data, model), ("data", "model"))
-
-
-# TPU v5e hardware constants (roofline denominators)
-PEAK_FLOPS_BF16 = 197e12        # per chip
-HBM_BW = 819e9                  # bytes/s per chip
-ICI_BW = 50e9                   # bytes/s per link
+    if data * model > n:
+        raise ValueError(f"need {data * model} devices, have {n}")
+    return make_auto_mesh((data, model), ("data", "model"))

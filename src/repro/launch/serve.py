@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import get_config, get_smoke_config
+from repro.launch.cache import enable_compile_cache
 from repro.models import transformer as T
 
 
@@ -335,6 +336,7 @@ def main() -> None:
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.from_sim is not None:
         serve_from_sim(args.from_sim, args.task, args.job, args.batch,

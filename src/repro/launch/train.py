@@ -20,6 +20,7 @@ from repro.checkpoint import save_pytree
 from repro.configs.base import get_config, get_smoke_config
 from repro.core.fed_step import FedConfig, make_fed_train_step
 from repro.data import make_token_batch
+from repro.launch.cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.optim import adamw, apply_updates, clip_by_global_norm
 
@@ -41,6 +42,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--ckpt", default="")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     print(f"[train] {cfg.name}: {cfg.n_layers}L d={cfg.d_model} "

@@ -115,18 +115,9 @@ def shard(x: jax.Array, *logical: Optional[str]) -> jax.Array:
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions.
-
-    jax >= 0.5 exposes it as ``jax.shard_map(..., check_vma=...)``; on the
-    0.4.x line only ``jax.experimental.shard_map`` exists and the replication
-    check flag is spelled ``check_rep``.  All repo call sites go through this
-    wrapper so the model code stays version-agnostic."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+    """``jax.shard_map``: the one spelling every call site goes through."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 # ----------------------------------------------------------------------

@@ -49,3 +49,14 @@ def test_codec_throughput_prices_identity_dense():
     row = bench_codec("identity", tree, 0.25, 8, reps=1)
     assert row["wire_bytes"] == row["dense_bytes"]
     assert row["compression_x"] == 1.0
+
+
+def test_host_tuning_refuses_after_backend_init(monkeypatch):
+    """The re-exec must come before JAX holds a backend: afterwards the
+    process would re-exec while holding the device."""
+    import jax
+    from benchmarks.common import _HOST_TUNED_MARKER, maybe_reexec_host_tuned
+    monkeypatch.delenv(_HOST_TUNED_MARKER, raising=False)
+    jax.devices()
+    with pytest.raises(RuntimeError, match="before any JAX backend"):
+        maybe_reexec_host_tuned(True, host_devices=2)

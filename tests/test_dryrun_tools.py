@@ -99,13 +99,20 @@ def test_roofline_analyze():
         "collectives": {"total": 5e10},
         "memory": {"temp_size_in_bytes": 10 ** 10},
     }
-    row = RL.analyze(rec, 256)
+    row = RL.analyze(rec, 256, "TPU v5 lite")
     assert row["dominant"] == "memory"
     np.testing.assert_allclose(row["compute_s"], 1e13 / 197e12)
     np.testing.assert_allclose(row["collective_s"], 1.0)
     # uses the trip-aware flops, not the raw ones
     expected_ratio = (6 * 2e9 * 4096 * 256) / (1e13 * 256)
     np.testing.assert_allclose(row["useful_ratio"], expected_ratio)
+
+
+def test_roofline_peaks_refuse_unknown_kind():
+    from benchmarks import roofline as RL
+    assert RL.peaks_for("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
+    with pytest.raises(ValueError, match="no published peaks"):
+        RL.peaks_for("cpu")
 
 
 def test_input_specs_cover_all_modalities():

@@ -99,6 +99,7 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.configs.base import get_smoke_config
 from repro.models import transformer as T
 from repro.core.fed_step import FedConfig, make_fed_train_step
+from repro.launch.mesh import make_host_mesh
 from repro.sharding.rules import Rules, use_rules, param_shardings
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -115,7 +116,7 @@ stale = jnp.asarray([0, 2], jnp.int32)
 p_ref, m_ref = jax.jit(step)(params, batch, stale)
 
 # 2x2 mesh (data=fed groups, model=TP/EP)
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_host_mesh(data=2, model=2)
 rules = Rules(mesh)
 with use_rules(rules), mesh:
     p_mesh, m_mesh = jax.jit(step)(params, batch, stale)

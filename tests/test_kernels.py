@@ -1,7 +1,8 @@
 """Pallas kernel validation: shape/dtype sweeps against pure-jnp oracles.
 
-Kernels run in interpret=True mode (CPU container); bodies are the same code
-that lowers to TPU pallas_call + BlockSpec.
+On the CPU backend the kernels run under the Pallas interpreter; the bodies
+are the same code that lowers to TPU pallas_call + BlockSpec
+(tests/test_chip_compile.py compiles them for a v5e chip).
 """
 import jax
 import jax.numpy as jnp

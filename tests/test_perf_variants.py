@@ -67,6 +67,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs.base import get_smoke_config
+from repro.launch.mesh import make_host_mesh
 from repro.models import transformer as T
 from repro.sharding.rules import Rules, use_rules
 
@@ -75,7 +76,7 @@ params = T.init_model(jax.random.PRNGKey(0), cfg)
 S = 16
 toks = jnp.asarray(np.random.RandomState(1).randint(0, cfg.vocab, (4, S)), jnp.int32)
 full, _ = T.forward(params, {"tokens": toks}, cfg)
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_host_mesh(data=2, model=2)
 cache = T.init_decode_state(cfg, 4, S, dtype=jnp.float32)
 errs = []
 with use_rules(Rules(mesh)), mesh:

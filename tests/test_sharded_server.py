@@ -104,6 +104,15 @@ def test_degenerate_sharded_has_no_mesh():
     assert srv.mesh is None and srv._agg is None
 
 
+@pytest.mark.smoke
+def test_sharded_rejects_more_shards_than_devices():
+    """A mesh wider than the process's devices is refused, not capped."""
+    want = len(jax.devices()) + 1
+    with pytest.raises(ValueError, match=f"server_shards={want}"):
+        make_server("sharded", {"w": np.zeros(3, np.float32)},
+                    ServerConfig(n_devices=10), shards=want)
+
+
 def test_engine_rejects_unknown_server(tiny_setup):
     from repro.fl.protocols import make_sim
     from repro.fl.simulator import SimConfig

@@ -17,6 +17,7 @@ from repro.checkpoint import load_pytree, save_pytree
 from repro.core.latency import WirelessConfig, comm_latency, device_rates
 from repro.data import (make_fmnist_like, partition_dirichlet, partition_iid,
                         partition_noniid_classes)
+from repro.launch.mesh import make_host_mesh
 from repro.optim import adamw, apply_updates, clip_by_global_norm, sgd
 from repro.sharding.rules import Rules, logical_axes_for
 
@@ -94,10 +95,27 @@ def test_checkpoint_roundtrip():
     assert int(out["step"]) == 7
 
 
+# -- compile cache -----------------------------------------------------------
+def test_compile_cache_dir(monkeypatch):
+    """An exported JAX_COMPILATION_CACHE_DIR is left alone; otherwise the
+    cache goes to the fixed <checkout>/.jax_cache."""
+    from repro.launch import cache
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(cache.ENV_VAR, "/elsewhere/cache")
+        assert cache.enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == was
+        monkeypatch.delenv(cache.ENV_VAR)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert cache.enable_compile_cache() == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == cache.DEFAULT_DIR
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
 # -- sharding rules --------------------------------------------------------
 def test_spec_drops_nondivisible_axes():
-    import jax as _jax
-    mesh = _jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh(data=1, model=1)
     rules = Rules(mesh, mapping={"heads": "model"})
     # 9 heads on 1-way model axis: divisible, kept
     assert rules.spec(("batch", "heads"), (4, 9))[1] == "model"
