@@ -24,6 +24,7 @@ import functools
 import math
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from repro import spans
 from repro.core.staleness import (aggregate_cache, aggregate_cache_stacked,
                                   make_sharded_aggregator)
 
@@ -77,6 +78,13 @@ class TeasqServer:
         return aggregate_cache_stacked(self.w, self.cache, self.t,
                                        self.cfg.alpha, self.cfg.a)
 
+    def _fold_span(self):
+        """The span around one fold; ``nbytes`` counts the cached updates
+        that are on the host, which the fold moves to the device."""
+        return spans.span("fl.aggregate", nbytes=spans.nbytes(
+            [c[0] for c in self.cache], host_only=True)
+            if spans.enabled() else 0)
+
     def receive(self, w_local: Any, h: int, n_samples: int) -> bool:
         """Push an update; aggregate when the cache reaches K.
         Returns True if an aggregation round completed."""
@@ -84,7 +92,8 @@ class TeasqServer:
         self.cache.append((w_local, h, n_samples))
         if len(self.cache) < self.cfg.cache_size:
             return False
-        self.w = self._aggregate()
+        with self._fold_span():
+            self.w = self._aggregate()
         self.cache.clear()
         self.t += 1
         return True
@@ -105,7 +114,8 @@ class TeasqServer:
             if len(self.cache) < self.cfg.cache_size:
                 done.append(False)
                 continue
-            self.w = self._aggregate_stacked()
+            with self._fold_span():
+                self.w = self._aggregate_stacked()
             self.cache.clear()
             self.t += 1
             done.append(True)

@@ -66,6 +66,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.core.client import local_update
 from repro.core.codecs import Codec, IdentityCodec, ThresholdGraphCodec
 from repro.core.latency import (comm_latency, comm_latency_batch,
@@ -411,11 +412,6 @@ def _cohort_round(w_versions, vidx, xs, ys, didx, bidx, valid, *,
 
     channel = ThresholdGraphCodec(p_s, p_q, iters).apply_tree
 
-    w_recv_v = jax.vmap(channel)(w_versions)
-    w_recv = jax.tree.map(lambda a: a[vidx], w_recv_v)
-    xd = xs[didx]
-    yd = ys[didx]
-
     def step(params, sv):
         idx, v = sv                                   # (C, bs), (C,)
         # broadcast the (C, bs) gather over the sample feature axes, whatever
@@ -431,8 +427,16 @@ def _cohort_round(w_versions, vidx, xs, ys, didx, bidx, valid, *,
 
         return jax.tree.map(upd, params, grads, w_recv), None
 
-    out, _ = jax.lax.scan(step, w_recv, (bidx, valid))
-    return jax.vmap(channel)(out)
+    # the named scopes mark the codec's and the scan's ops in the trace
+    with jax.named_scope("codec_down"):
+        w_recv_v = jax.vmap(channel)(w_versions)
+        w_recv = jax.tree.map(lambda a: a[vidx], w_recv_v)
+    with jax.named_scope("prox_sgd"):
+        xd = xs[didx]
+        yd = ys[didx]
+        out, _ = jax.lax.scan(step, w_recv, (bidx, valid))
+    with jax.named_scope("codec_up"):
+        return jax.vmap(channel)(out)
 
 
 @functools.partial(jax.jit, static_argnames=("p_s", "p_q", "iters"))
@@ -446,7 +450,14 @@ def _zero_step_round(w_versions, *, p_s: float, p_q: int, iters: int):
     ~K-fold cut in channel work).  Per-task results are gathers of the
     (V, ...) output on the host side."""
     channel = ThresholdGraphCodec(p_s, p_q, iters).apply_tree
-    return jax.vmap(lambda w: channel(channel(w)))(w_versions)
+
+    def down_up(w):
+        with jax.named_scope("codec_down"):
+            w = channel(w)
+        with jax.named_scope("codec_up"):
+            return channel(w)
+
+    return jax.vmap(down_up)(w_versions)
 
 
 class CohortTrainer:
@@ -534,16 +545,21 @@ class CohortTrainer:
         self._version_ids = {}
         if not tasks:
             return
-        groups: Dict[Tuple[float, int], List[PendingTask]] = {}
-        for t in tasks:
-            groups.setdefault((t.p_s, t.p_q), []).append(t)
-        # pad the version axis to a power of two (repeat the first version)
-        # so the jitted program's V dimension comes from a small bucket set
-        versions = versions + [versions[0]] * (self._pad_pow2(len(versions))
-                                               - len(versions))
-        w_versions = jax.tree.map(lambda *ls: jnp.stack(ls), *versions)
-        for (p_s, p_q), group in groups.items():
-            self._flush_group(group, w_versions, p_s, p_q)
+        with spans.span("fl.flush", tasks=len(tasks)):
+            groups: Dict[Tuple[float, int], List[PendingTask]] = {}
+            for t in tasks:
+                groups.setdefault((t.p_s, t.p_q), []).append(t)
+            # pad the version axis to a power of two (repeat the first
+            # version) so the jitted program's V dimension comes from a
+            # small bucket set
+            versions = versions + [versions[0]] * (
+                self._pad_pow2(len(versions)) - len(versions))
+            with spans.span("fl.flush.stage", nbytes=spans.nbytes(
+                    versions, host_only=True) if spans.enabled() else 0):
+                w_versions = jax.tree.map(lambda *ls: jnp.stack(ls),
+                                          *versions)
+            for (p_s, p_q), group in groups.items():
+                self._flush_group(group, w_versions, p_s, p_q)
         self.engine.stats.flushes += 1
         self.engine.stats.flushed_tasks += len(tasks)
 
@@ -562,9 +578,11 @@ class CohortTrainer:
             # zero local steps => the result is a pure function of the
             # version; gated to wave mode so the serial path keeps running
             # the exact pinned _cohort_round program
-            w_up_v = _zero_step_round(w_versions, p_s=p_s, p_q=p_q,
-                                      iters=self.channel_iters)
-            w_np = jax.tree.map(np.asarray, w_up_v)
+            program = "jit__zero_step_round"
+            with spans.span("fl.flush.launch", program=program):
+                w_up_v = _zero_step_round(w_versions, p_s=p_s, p_q=p_q,
+                                          iters=self.channel_iters)
+            w_np = self._to_host(w_up_v, program)
             for t in group:
                 t.result = (jax.tree.map(lambda a, v=t.version: a[v], w_np),
                             t.n_k)
@@ -574,25 +592,42 @@ class CohortTrainer:
         valid = np.zeros((c_pad, t_max), np.float32)
         vidx = np.zeros(c_pad, np.int32)
         didx = np.zeros(c_pad, np.int32)
-        for i, t in enumerate(group):
-            ti = t.bidx.shape[0]
-            bidx[i, :ti] = t.bidx
-            valid[i, :ti] = 1.0
-            vidx[i] = t.version
-            didx[i] = t.k
-        w_up = _cohort_round(
-            w_versions, jnp.asarray(vidx), self.xs, self.ys,
-            jnp.asarray(didx), jnp.asarray(np.swapaxes(bidx, 0, 1)),
-            jnp.asarray(np.swapaxes(valid, 0, 1)),
-            cohort_loss=self.engine.task.cohort_loss,
-            lr=cfg.lr, mu=cfg.mu, p_s=p_s, p_q=p_q,
-            iters=self.channel_iters)
-        # one bulk device->host transfer per leaf; per-task results are then
-        # free numpy views (a per-task jnp slice costs an eager dispatch,
-        # which dominated the flush at large N)
-        w_up_np = jax.tree.map(np.asarray, w_up)
+        staged = (vidx, didx, bidx, valid)
+        with spans.span("fl.flush.stage",
+                        nbytes=sum(a.nbytes for a in staged)):
+            for i, t in enumerate(group):
+                ti = t.bidx.shape[0]
+                bidx[i, :ti] = t.bidx
+                valid[i, :ti] = 1.0
+                vidx[i] = t.version
+                didx[i] = t.k
+            vidx_d, didx_d, bidx_d, valid_d = (
+                jnp.asarray(vidx), jnp.asarray(didx),
+                jnp.asarray(np.swapaxes(bidx, 0, 1)),
+                jnp.asarray(np.swapaxes(valid, 0, 1)))
+        program = "jit__cohort_round"
+        with spans.span("fl.flush.launch", program=program):
+            w_up = _cohort_round(
+                w_versions, vidx_d, self.xs, self.ys, didx_d, bidx_d,
+                valid_d, cohort_loss=self.engine.task.cohort_loss,
+                lr=cfg.lr, mu=cfg.mu, p_s=p_s, p_q=p_q,
+                iters=self.channel_iters)
+        w_up_np = self._to_host(w_up, program)
         for i, t in enumerate(group):
             t.result = (jax.tree.map(lambda a, i=i: a[i], w_up_np), t.n_k)
+
+    @staticmethod
+    def _to_host(tree: Any, program: str) -> Any:
+        """``tree`` (the result of ``program``) as numpy: one bulk
+        device->host transfer per leaf, so that per-task results are free
+        numpy views (a per-task jnp slice costs an eager dispatch, which
+        dominated the flush at large N).  The wait for the device and the
+        copy are spans of their own."""
+        with spans.span("fl.flush.wait", program=program):
+            jax.block_until_ready(tree)
+        with spans.span("fl.flush.copy", nbytes=spans.nbytes(tree)
+                        if spans.enabled() else 0):
+            return jax.tree.map(np.asarray, tree)
 
 
 # ----------------------------------------------------------------------
@@ -707,10 +742,12 @@ class FLEngine:
     def evaluate(self) -> float:
         xs, ys = self.data["x_test"], self.data["y_test"]
         accs = []
-        for s in range(0, len(ys), 2000):
-            accs.append(float(self._eval(self.server.w,
-                                         jnp.asarray(xs[s:s + 2000]),
-                                         jnp.asarray(ys[s:s + 2000]))))
+        with spans.span("fl.evaluate", nbytes=spans.nbytes(
+                (xs, ys), host_only=True) if spans.enabled() else 0):
+            for s in range(0, len(ys), 2000):
+                accs.append(float(self._eval(self.server.w,
+                                             jnp.asarray(xs[s:s + 2000]),
+                                             jnp.asarray(ys[s:s + 2000]))))
         return float(np.mean(accs))
 
     def _log(self, time: float) -> None:
@@ -722,9 +759,10 @@ class FLEngine:
     # -- entry point -------------------------------------------------------
     def run(self, time_budget: float = 300.0, max_rounds: int = 10 ** 9,
             eval_every: int = 1) -> List[LogEntry]:
-        if not self.strategy.event_driven:
-            return self._run_sync(time_budget, max_rounds, eval_every)
-        return self._run_async(time_budget, max_rounds, eval_every)
+        with spans.span("fl.run"):
+            if not self.strategy.event_driven:
+                return self._run_sync(time_budget, max_rounds, eval_every)
+            return self._run_async(time_budget, max_rounds, eval_every)
 
     # -- asynchronous event loop (Algs. 1-2) -------------------------------
     def _resume(self) -> None:

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.configs.base import get_config, get_smoke_config
 from repro.launch.cache import enable_compile_cache
 from repro.models import transformer as T
@@ -42,13 +43,19 @@ from repro.models import transformer as T
 # ----------------------------------------------------------------------
 # jit caches — keyed on the (frozen, hashable) ModelConfig so repeated
 # generate()/ContinuousBatcher calls over the same config reuse the
-# compiled step instead of re-tracing per call
+# compiled step instead of re-tracing per call.  Each jits a named
+# function, so that its program has a stable name in a profiler trace
+# (``jit_serial_step``, ``jit_prefill``, ``jit_extend_cache``, ``jit_step``).
 # ----------------------------------------------------------------------
 
 @functools.lru_cache(maxsize=None)
 def _serial_step(cfg):
     """(params, tok (B,1), pos scalar, cache) -> (logits, cache)."""
-    return jax.jit(lambda p, t, pos, c: T.decode_step(p, t, pos, cfg, c))
+
+    def serial_step(p, t, pos, c):
+        return T.decode_step(p, t, pos, cfg, c)
+
+    return jax.jit(serial_step)
 
 
 @functools.lru_cache(maxsize=None)
@@ -58,7 +65,11 @@ def _prefill_jit(cfg):
     ``generate`` and ``ContinuousBatcher`` so a batcher admission runs the
     exact compiled program a solo generate does (token-parity).  One
     compile per (batch, prompt_len) shape."""
-    return jax.jit(lambda p, toks: T.prefill(p, {"tokens": toks}, cfg))
+
+    def prefill(p, toks):
+        return T.prefill(p, {"tokens": toks}, cfg)
+
+    return jax.jit(prefill)
 
 
 @functools.lru_cache(maxsize=None)
@@ -66,7 +77,11 @@ def _extend_jit(cfg, cache_len):
     """Jitted ``extend_cache`` — zero-pads the sequence axis out to the
     resident ``cache_len``, same values as the eager path."""
     del cfg
-    return jax.jit(lambda c: T.extend_cache(c, cache_len))
+
+    def extend_cache(c):
+        return T.extend_cache(c, cache_len)
+
+    return jax.jit(extend_cache)
 
 
 @functools.lru_cache(maxsize=None)
@@ -172,6 +187,7 @@ class ContinuousBatcher:
         self._first: Dict[int, int] = {}         # rid -> prefill argmax token
         self._slots_of: Dict[int, List[Tuple[int, int]]] = {}
         self._results: Dict[int, List[int]] = {}  # materialized on demand
+        self._submitted: Dict[int, float] = {}   # rid -> submit time (s)
         self.steps = 0                           # decode steps taken
 
     # -- request intake --------------------------------------------------
@@ -187,6 +203,7 @@ class ContinuousBatcher:
         rid = self._next_rid
         self._next_rid += 1
         self._queue.append((rid, prompt, int(gen)))
+        self._submitted[rid] = time.perf_counter()
         return rid
 
     def result(self, rid: int) -> List[int]:
@@ -214,15 +231,30 @@ class ContinuousBatcher:
             if self._rid[s] >= 0 or not self._queue:
                 continue
             rid, prompt, gen = self._queue.popleft()
+            queued_ms = (time.perf_counter() - self._submitted.pop(rid)) * 1e3
+            with spans.span("serve.admit", rid=rid, prompt_len=prompt.size,
+                            queued_ms=queued_ms):
+                if self._admit_one(s, rid, prompt, gen):
+                    done.append(rid)
+        return done
+
+    def _admit_one(self, s: int, rid: int, prompt: np.ndarray,
+                   gen: int) -> bool:
+        """Prefill request ``rid`` into slot ``s``; True if it completed
+        at admission (its prefill token is the whole answer)."""
+        with spans.span("serve.prefill", rid=rid, program="jit_prefill"):
             logits, one = _prefill_jit(self.cfg)(
                 self.params, jnp.asarray(prompt[None, :]))
+        with spans.span("serve.prefill", rid=rid, program="jit_extend_cache"):
             one = _extend_jit(self.cfg, self.cache_len)(one)
+        # the host waits here for the prefill: the request's first token
+        with spans.span("serve.first_token", rid=rid, program="jit__argmax"):
             first = int(jnp.argmax(logits[0, -1]))
-            self._first[rid] = first
-            self._slots_of[rid] = []
-            if gen == 1:
-                done.append(rid)
-                continue
+        self._first[rid] = first
+        self._slots_of[rid] = []
+        if gen == 1:
+            return True
+        with spans.span("serve.splice", rid=rid):
             if self._cache is None:
                 self._cache = jax.tree.map(
                     lambda a: jnp.zeros(
@@ -231,18 +263,26 @@ class ContinuousBatcher:
             self._cache, self._tok, self._pos = _slot_insert(self.cfg)(
                 self._cache, one, self._tok, self._pos, jnp.int32(s),
                 jnp.int32(first), jnp.int32(prompt.size))
-            self._rid[s] = rid
-            self._remaining[s] = gen - 1
-        return done
+        self._rid[s] = rid
+        self._remaining[s] = gen - 1
+        return False
 
     def step(self) -> List[int]:
         """Admit from the queue, then advance every active slot one
         token.  Returns the rids that completed this step."""
+        on = spans.enabled()
+        with spans.span("serve.step",
+                        active=sum(r >= 0 for r in self._rid) if on else 0,
+                        queued=len(self._queue)):
+            return self._step()
+
+    def _step(self) -> List[int]:
         done = self._admit()
         if not any(r >= 0 for r in self._rid):
             return done
-        self._tok, self._pos, self._cache = _batched_step(self.cfg)(
-            self.params, self._tok, self._pos, self._cache)
+        with spans.span("serve.decode", step=self.steps):
+            self._tok, self._pos, self._cache = _batched_step(self.cfg)(
+                self.params, self._tok, self._pos, self._cache)
         self._trace.append(self._tok)
         k = self.steps
         self.steps += 1
