@@ -84,13 +84,26 @@ def _extend_jit(cfg, cache_len):
     return jax.jit(extend_cache)
 
 
+def _decode_path(cfg) -> str:
+    """Which batched decode ``_batched_step`` compiles for ``cfg``: in
+    place for a plain attention stack, else the vmap."""
+    if cfg.is_encoder_decoder or cfg.is_hybrid or cfg.is_ssm_only:
+        return "vmap"
+    return "inplace"
+
+
 @functools.lru_cache(maxsize=None)
 def _batched_step(cfg):
     """Per-row decode: tok (B,1) int32, pos (B,) int32 — each row advances
     at its OWN absolute position (slots hold requests of different ages).
-    Wraps the scalar-position ``decode_step`` in a vmap over the batch
-    axis (axis 1 of the stacked (L, B, ...) cache leaves), re-adding the
-    size-1 batch dim inside.  Returns (next greedy token (B,), cache)."""
+    Returns (next greedy token (B,1), pos + 1, cache); the cache argument
+    is donated, so the output cache takes over its buffers.
+
+    A plain attention stack runs ``decode_step_rows``, which writes each
+    row's new K/V into the resident cache in place.  Other families wrap
+    the scalar-position ``decode_step`` in a vmap over the batch axis
+    (axis 1 of the stacked (L, B, ...) cache leaves), re-adding the
+    size-1 batch dim inside."""
 
     def one(params, tok, pos, c):
         c1 = jax.tree.map(lambda a: a[:, None], c)
@@ -98,14 +111,19 @@ def _batched_step(cfg):
         return logits[0, -1], jax.tree.map(lambda a: a[:, 0], c1)
 
     def step(params, toks, poss, cache):
-        logits, cache = jax.vmap(one, in_axes=(None, 0, 0, 1),
-                                 out_axes=(0, 1))(params, toks, poss, cache)
+        if _decode_path(cfg) == "inplace":
+            logits, cache = T.decode_step_rows(params, toks, poss, cfg, cache)
+            logits = logits[:, -1]
+        else:
+            logits, cache = jax.vmap(one, in_axes=(None, 0, 0, 1),
+                                     out_axes=(0, 1))(params, toks, poss,
+                                                      cache)
         # pos advances for every slot on-device; a free slot harmlessly
-        # decodes garbage at a clamped position until it is re-admitted
+        # decodes garbage past its request until it is re-admitted
         return (logits.argmax(-1).astype(jnp.int32)[:, None], poss + 1,
                 cache)
 
-    return jax.jit(step)
+    return jax.jit(step, donate_argnums=3)
 
 
 @functools.lru_cache(maxsize=None)
@@ -280,7 +298,8 @@ class ContinuousBatcher:
         done = self._admit()
         if not any(r >= 0 for r in self._rid):
             return done
-        with spans.span("serve.decode", step=self.steps):
+        with spans.span("serve.decode", step=self.steps,
+                        path=_decode_path(self.cfg)):
             self._tok, self._pos, self._cache = _batched_step(self.cfg)(
                 self.params, self._tok, self._pos, self._cache)
         self._trace.append(self._tok)

@@ -278,6 +278,31 @@ def attn_decode(p, x, pos, cfg, cache, *, rolling: bool = False,
     return shard(o, "batch", "seq", "d_model"), new_cache
 
 
+def attn_decode_rows(p, x, pos, cfg, k_all, v_all, layer
+                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """One-token decode with each row at its own position, against layer
+    ``layer`` of a stacked (L, B, S, G, hd) cache.  x: (B,1,D); pos: (B,)
+    int32.  Writes only the B new K/V rows into ``k_all``/``v_all`` (a row
+    whose position is past the cache writes nothing) and reads the layer's
+    slab where it lies.  Same math as ``attn_decode`` per row."""
+    B = x.shape[0]
+    positions = pos[:, None]
+    q = _project_q(p, x, positions, cfg, rope=True)
+    k_new, v_new = _project_kv(p, x, positions, cfg, rope=True)
+    rows = jnp.arange(B)
+    k_all = k_all.at[layer, rows, pos].set(k_new[:, 0].astype(k_all.dtype),
+                                           mode="drop")
+    v_all = v_all.at[layer, rows, pos].set(v_new[:, 0].astype(v_all.dtype),
+                                           mode="drop")
+    mask = jnp.arange(k_all.shape[2])[None, :] <= positions      # (B,S)
+    s = _grouped_scores(q, k_all[layer])                          # (B,G,rep,1,S)
+    s = jnp.where(mask[:, None, None, None, :], s, NEG_INF)
+    probs = jax.nn.softmax(s, axis=-1)
+    o = _grouped_out(probs, v_all[layer], x.dtype)                # (B,1,H,hd)
+    o = o.reshape(B, 1, -1) @ p["wo"]
+    return shard(o, "batch", "seq", "d_model"), k_all, v_all
+
+
 # -- sequence-sharded decode (beyond-paper: MQA/GQA KV too small to TP) ---
 def attn_decode_seqshard(p, x, pos, cfg, cache) -> Tuple[jax.Array, dict]:
     """One-token decode with the KV cache sharded along SEQUENCE over the

@@ -10,6 +10,8 @@ Public entry points:
   forward(params, batch, cfg)             -> (logits, aux)   # train / prefill
   init_decode_state(cfg, batch, cache_len, dtype, rolling)   -> cache pytree
   decode_step(params, tokens, pos, cfg, cache)  -> (logits, new cache)
+  decode_step_rows(params, tokens, pos, cfg, cache) -> (logits, new cache)
+                                          # per-row positions, in place
   lm_loss(params, batch, cfg)             -> (loss, aux)
 """
 from __future__ import annotations
@@ -512,3 +514,36 @@ def decode_step(params, tokens, pos, cfg, cache, *, rolling: bool = False,
     logits = lm_head(x, params["embed"] if cfg.tie_embeddings else None,
                      params.get("lm_head"))
     return logits, new_cache
+
+
+def decode_step_rows(params, tokens, pos, cfg, cache) -> Tuple[jax.Array, Any]:
+    """tokens: (B, 1) int32; pos: (B,) int32, each row at its own absolute
+    position.  For a plain attention stack (dense or MoE FFN; not
+    encoder-decoder, hybrid or SSM) with an unquantized {k, v} cache.
+
+    The stacked (L, B, S, G, hd) K and V ride the layer loop's carry, so
+    each layer writes only its B new rows; under a donating jit the cache
+    is updated in place.  Per row, the same math as ``decode_step``."""
+    x = embed_lookup(params["embed"], tokens)
+    x = shard(x, "batch", "seq", "d_model")
+
+    def body(carry, xs):
+        x, k, v = carry
+        lp, l = xs
+        h = rmsnorm(lp["norm1"], x, cfg.norm_eps)
+        o, k, v = attn.attn_decode_rows(lp["attn"], h, pos, cfg, k, v, l)
+        x = x + o
+        if cfg.is_moe:
+            y, _ = moe_mod.moe_apply(lp["moe"], rmsnorm(lp["norm2"], x, cfg.norm_eps), cfg)
+            x = x + y
+        elif cfg.d_ff > 0:
+            x = x + mlp(lp["ffn"], rmsnorm(lp["norm2"], x, cfg.norm_eps))
+        return (x, k, v), None
+
+    (x, k, v), _ = jax.lax.scan(
+        body, (x, cache["k"], cache["v"]),
+        (params["layers"], jnp.arange(cache["k"].shape[0])))
+    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+    logits = lm_head(x, params["embed"] if cfg.tie_embeddings else None,
+                     params.get("lm_head"))
+    return logits, dict(cache, k=k, v=v)

@@ -1,7 +1,7 @@
 """Compiles for a TPU v5e chip that is described, not attached.
 
-Every Pallas kernel under ``src/repro/kernels/`` and the cohort trainer's
-jitted round are compiled by the TPU compiler at the main path's real
+Every Pallas kernel under ``src/repro/kernels/``, the cohort trainer's
+jitted round and the serving decode step are compiled by the TPU compiler at the main path's real
 sizes, so a kernel the chip would refuse (an unaligned block, a primitive
 Mosaic cannot lower, more VMEM than a kernel may hold) fails here, on the
 CPU.  A compile that passes is not a chip run: nothing executes.
@@ -9,6 +9,7 @@ CPU.  A compile that passes is not a chip run: nothing executes.
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU library.
 """
+import math
 import os
 import subprocess
 import sys
@@ -18,11 +19,14 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs.base import get_config
 from repro.core.compression import topk_count
 from repro.fl.engine import _cohort_round
 from repro.fl.simulator import SimConfig
 from repro.fl.tasks import get_task
 from repro.kernels import fused_pack, ssd_scan, topk_quant
+from repro.launch import serve
+from repro.models import transformer as T
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FC1 = 200704                    # fmnist_cnn's largest leaf (fc1 weight)
@@ -106,6 +110,30 @@ def test_cohort_round_compiles_at_paper_size(one_chip):
         mu=cfg.mu, p_s=0.25, p_q=8, iters=cfg.cohort_channel_iters).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 * 2**30
+
+
+def test_batched_decode_updates_the_cache_in_place(one_chip):
+    """qwen3-1.7b's batched decode step at the chat cell's 32 slots of
+    1536 positions: the output cache aliases the donated input, and the
+    step needs no temporary as large as one layer's K slab (the vmap of
+    the scalar-position step copied slabs and needed 101.9 MB)."""
+    cfg = get_config("qwen3-1.7b")
+    B, S = 32, 1536
+    params = jax.tree.map(
+        lambda a: _spec(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda: T.init_model(jax.random.PRNGKey(0), cfg,
+                                            jnp.bfloat16)))
+    kv = _spec(one_chip, (cfg.n_layers, B, S, cfg.n_kv_heads, cfg.head_dim),
+               jnp.bfloat16)
+    compiled = serve._batched_step(cfg).lower(
+        params, _spec(one_chip, (B, 1), jnp.int32),
+        _spec(one_chip, (B,), jnp.int32), {"k": kv, "v": kv}).compile()
+    mem = compiled.memory_analysis()
+    cache_bytes = 2 * math.prod(kv.shape) * 2
+    slab_bytes = B * S * cfg.n_kv_heads * cfg.head_dim * 2
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < slab_bytes
+    assert compiled.as_text().startswith("HloModule jit_step,")
 
 
 def test_chip_smoke_refuses_cpu():

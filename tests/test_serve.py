@@ -25,10 +25,13 @@ import numpy as np
 import pytest
 
 from repro.checkpoint.io import load_sim_params, save_blob
+from repro.configs.base import get_smoke_config
 from repro.fl.protocols import make_setup, make_sim
 from repro.fl.simulator import SimConfig
 from repro.fl.tasks import get_task
+from repro.launch import serve
 from repro.launch.serve import ContinuousBatcher, generate, load_task_params
+from repro.models import transformer as T
 
 P_LEN, GEN = 8, 6
 
@@ -117,6 +120,125 @@ def test_batcher_serves_moe_lm():
                        )[0, P_LEN:].tolist() for p in prompts]
     cb = ContinuousBatcher(params, cfg, slots=2, cache_len=P_LEN + GEN)
     outs, _ = cb.run(prompts, GEN)
+    assert outs == solo
+
+
+# ----------------------------------------------------------------------
+# the batched decode step: in place for plain attention stacks
+# ----------------------------------------------------------------------
+def _vmap_step(cfg):
+    """The batched decode as a vmap of the scalar-position ``decode_step``
+    over the batch axis (axis 1 of the stacked cache): the reference for
+    the in-place path.  Returns (last-position logits (B, V), cache)."""
+
+    def one(params, tok, pos, c):
+        c1 = jax.tree.map(lambda a: a[:, None], c)
+        logits, c1 = T.decode_step(params, tok[None, :], pos, cfg, c1)
+        return logits[0, -1], jax.tree.map(lambda a: a[:, 0], c1)
+
+    def step(params, toks, poss, cache):
+        return jax.vmap(one, in_axes=(None, 0, 0, 1),
+                        out_axes=(0, 1))(params, toks, poss, cache)
+
+    return jax.jit(step)
+
+
+def _backend_donates() -> bool:
+    x = jnp.zeros(4)
+    jax.jit(lambda a: a + 1, donate_argnums=0)(x)
+    return x.is_deleted()
+
+
+BF16_TOL = dict(rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("task_name", ["transformer_lm", "moe_lm"])
+def test_inplace_step_matches_vmap_reference(task_name):
+    """Slots at different positions, a free slot whose position runs past
+    the cache, and a slot recycled for a shorter request: the in-place
+    step's greedy tokens equal the vmap reference's, and its logits and
+    every active slot's cache rows agree within bf16 tolerance."""
+    task = get_task(task_name)
+    cfg = task.model_cfg
+    assert serve._decode_path(cfg) == "inplace"
+    params = jax.tree.map(lambda a: a.astype(jnp.bfloat16),
+                          task.init_params(jax.random.PRNGKey(3)))
+    S, B = 16, 4
+    rng = np.random.RandomState(4)
+    ins = serve._slot_insert(cfg)
+
+    def admit(states, s, n):
+        logits, one = serve._prefill_jit(cfg)(
+            params, jnp.asarray(rng.randint(0, cfg.vocab, (1, n)), jnp.int32))
+        one = serve._extend_jit(cfg, S)(one)
+        first = jnp.int32(jnp.argmax(logits[0, -1]))
+        return [ins(cache, one, tok, pos, jnp.int32(s), first, jnp.int32(n))
+                for cache, tok, pos in states]
+
+    shape = (cfg.n_layers, B, S, cfg.n_kv_heads, cfg.head_dim)
+    key = jax.random.PRNGKey(5)
+    stale = jax.random.normal(key, shape).astype(jnp.bfloat16)
+    state = ({"k": stale, "v": -stale}, jnp.zeros((B, 1), jnp.int32),
+             jnp.array([0, 0, 0, S + 2], jnp.int32))   # slot 3: free, past S
+    for s, n in enumerate([3, 9, 6]):
+        state, = admit([state], s, n)
+    ref, new = state, state
+    step, ref_step = serve._batched_step(cfg), _vmap_step(cfg)
+    rows = jax.jit(lambda p, t, q, c: T.decode_step_rows(p, t, q, cfg, c)[0])
+    for k in range(6):
+        if k == 3:                        # slot 1 frees; a shorter request
+            ref, new = admit([ref, new], 1, 2)
+        cache, tok, pos = new
+        ref_logits, ref_cache = ref_step(params, ref[1], ref[2], ref[0])
+        np.testing.assert_allclose(                  # slots 0-2 are active
+            np.asarray(rows(params, tok, pos, cache)[:3, -1]),
+            np.asarray(ref_logits[:3]), **BF16_TOL)
+        ref = (ref_cache, ref_logits.argmax(-1).astype(jnp.int32)[:, None],
+               ref[2] + 1)
+        tok, pos, cache = step(params, tok, pos,
+                               jax.tree.map(jnp.copy, cache))
+        new = (cache, tok, pos)
+        assert np.asarray(tok)[:3].tolist() == np.asarray(ref[1])[:3].tolist()
+        assert np.asarray(pos).tolist() == np.asarray(ref[2]).tolist()
+        for name in ("k", "v"):
+            np.testing.assert_allclose(
+                np.asarray(cache[name][:, :3], np.float32),
+                np.asarray(ref[0][name][:, :3], np.float32), **BF16_TOL)
+
+
+def test_decode_donates_the_cache_and_keeps_its_name(lm):
+    """One batcher step consumes the previous cache's buffers, and the
+    decode program is still ``jit_step`` (the name its trace is read by)."""
+    if not _backend_donates():
+        pytest.skip(f"the {jax.default_backend()} backend does not donate "
+                    "buffers, so no decode can update its cache in place")
+    params, cfg, prompts, _ = lm
+    cb = ContinuousBatcher(params, cfg, slots=2, cache_len=P_LEN + GEN)
+    cb.submit(prompts[0], GEN)
+    cb.step()
+    before = cb._cache
+    cb.step()
+    assert all(leaf.is_deleted() for leaf in jax.tree.leaves(before))
+    assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(cb._cache))
+    compiled = serve._batched_step(cfg).lower(
+        params, cb._tok, cb._pos, cb._cache).compile()
+    assert compiled.as_text().startswith("HloModule jit_step,")
+
+
+@pytest.mark.parametrize("arch", ["mamba2-370m", "jamba-v0.1-52b"])
+def test_ssm_and_hybrid_serve_through_vmap(arch):
+    """Families with SSM state keep the vmap of the scalar-position step,
+    and the batcher still decodes their solo tokens."""
+    cfg = get_smoke_config(arch)
+    assert serve._decode_path(cfg) == "vmap"
+    params = T.init_model(jax.random.PRNGKey(0), cfg)
+    rng = np.random.RandomState(0)
+    prompts = [rng.randint(0, cfg.vocab, P_LEN).astype(np.int32)
+               for _ in range(3)]
+    solo = [np.asarray(generate(params, cfg, jnp.asarray(p[None]), 4)
+                       )[0, P_LEN:].tolist() for p in prompts]
+    cb = ContinuousBatcher(params, cfg, slots=2, cache_len=P_LEN + 4)
+    outs, _ = cb.run(prompts, 4)
     assert outs == solo
 
 

@@ -135,6 +135,7 @@ def test_serving_spans_share_the_request_id(tmp_path):
     steps = [s for s in found if s[0] == "repro.serve.step"]
     decodes = [s for s in found if s[0] == "repro.serve.decode"]
     assert len(decodes) == cb.steps and len(steps) >= cb.steps
+    assert all(d[3]["path"] == "inplace" for d in decodes)
     assert all(any(_inside(d, s) for s in steps) for d in decodes)
     admits = [s for s in found if s[0] == "repro.serve.admit"]
     assert all(s[3]["prompt_len"] == 8 and s[3]["queued_ms"] >= 0
