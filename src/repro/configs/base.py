@@ -2,9 +2,10 @@
 
 Every assigned architecture gets a module in ``repro/configs/<id>.py`` exposing
 ``CONFIG`` (the exact assigned full-scale config) and ``smoke()`` (a reduced
-variant of the same family: <=2 layers, d_model<=512, <=4 experts) used by the
-CPU smoke tests.  Full configs are only ever lowered via ShapeDtypeStructs in
-the dry-run; they are never materialized.
+variant of the same family: <=2 layers after any leading dense ones,
+d_model<=512, <=4 experts held) used by the CPU smoke tests.  Full configs
+are only ever lowered via ShapeDtypeStructs in the dry-run; they are never
+materialized.
 """
 from __future__ import annotations
 
@@ -36,6 +37,22 @@ class ModelConfig:
     moe_top_k: int = 0
     moe_every: int = 1               # MoE FFN every k-th layer (1 = all layers)
     capacity_factor: float = 1.25
+    # DeepSeek-V3-style MoE (moe_router "sigmoid"): sigmoid scores, a
+    # per-expert bias for selection only, normalised top-k weights scaled
+    # by routed_scaling, shared experts on every token; dropless, and the
+    # layer computes only the routed experts [lo, hi) it holds
+    moe_router: str = "softmax"      # softmax (Mixtral top-k) | sigmoid
+    moe_d_ff: int = 0                # routed expert width; 0 => d_ff
+    n_shared_experts: int = 0        # acting as one SwiGLU of n * moe_d_ff
+    routed_scaling: float = 1.0
+    experts_held: Optional[Tuple[int, int]] = None   # None => all experts
+    first_k_dense: int = 0           # leading layers with a dense FFN
+
+    # multi-head latent attention (MLA); kv_lora_rank > 0 selects it
+    kv_lora_rank: int = 0            # latent width cached per position
+    qk_nope_dim: int = 0             # per-head query/key width without rope
+    qk_rope_dim: int = 0             # rope width, one key shared by heads
+    v_head_dim: int = 0
 
     # SSM (Mamba2 / SSD)
     ssm_state: int = 0               # N; 0 => no SSM layers
@@ -72,6 +89,19 @@ class ModelConfig:
         return self.n_experts > 0
 
     @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    @property
+    def held_experts(self) -> Tuple[int, int]:
+        """[lo, hi) of the routed experts this chip computes."""
+        return self.experts_held or (0, self.n_experts)
+
+    @property
     def is_ssm_only(self) -> bool:
         return self.ssm_state > 0 and self.attn_every == 0 and not self.is_encoder_decoder
 
@@ -95,6 +125,11 @@ class ModelConfig:
         hd = self.head_dim
 
         def attn_params() -> int:
+            if self.is_mla:
+                H, r, rope = self.n_heads, self.kv_lora_rank, self.qk_rope_dim
+                return (d * H * (self.qk_nope_dim + rope) + d * (r + rope) + r
+                        + r * H * (self.qk_nope_dim + self.v_head_dim)
+                        + H * self.v_head_dim * d)
             q = d * self.n_heads * hd
             kv = 2 * d * self.n_kv_heads * hd
             o = self.n_heads * hd * d
@@ -104,7 +139,11 @@ class ModelConfig:
             return (3 if self.gated_mlp else 2) * d * f
 
         def moe_ffn() -> int:
-            return self.n_experts * 3 * d * f + d * self.n_experts  # experts + router
+            fe = self.expert_d_ff
+            shared = 3 * d * self.n_shared_experts * fe
+            bias = self.n_experts if self.moe_router == "sigmoid" else 0
+            # experts + shared + router (+ selection bias)
+            return self.n_experts * 3 * d * fe + shared + d * self.n_experts + bias
 
         def ssm_params() -> int:
             di, n = self.d_inner, self.ssm_state
@@ -129,10 +168,13 @@ class ModelConfig:
             else:
                 total += attn_params()
             if self.ssm_state == 0 or self.is_hybrid:
-                use_moe = self.is_moe and (i % self.moe_every == self.moe_every - 1)
-                total += moe_ffn() if use_moe else dense_ffn()
+                total += moe_ffn() if self._moe_layer(i) else dense_ffn()
             total += 2 * d
         return total
+
+    def _moe_layer(self, i: int) -> bool:
+        return (self.is_moe and i >= self.first_k_dense
+                and i % self.moe_every == self.moe_every - 1)
 
     def active_param_count(self) -> int:
         """Params touched per token (MoE: top-k experts only)."""
@@ -141,10 +183,10 @@ class ModelConfig:
         full = self.param_count()
         n_moe_layers = sum(
             1 for i in range(self.n_layers)
-            if (self.ssm_state == 0 or self.is_hybrid)
-            and (i % self.moe_every == self.moe_every - 1)
+            if (self.ssm_state == 0 or self.is_hybrid) and self._moe_layer(i)
         )
-        inactive = n_moe_layers * (self.n_experts - self.moe_top_k) * 3 * self.d_model * self.d_ff
+        inactive = (n_moe_layers * (self.n_experts - self.moe_top_k) * 3
+                    * self.d_model * self.expert_d_ff)
         return full - inactive
 
 

@@ -17,8 +17,9 @@ Two entry styles:
         --task transformer_lm --job 0 --batch 4 --requests 8 --gen 16
 
 ``ContinuousBatcher`` holds a fixed number of decode slots; each step it
-admits queued requests into free slots (prefill one row, splice its KV
-cache into the batched cache) and advances every active slot one token —
+admits queued requests into free slots (prefill one row, splice its KV —
+or MLA latent — cache into the batched cache) and advances every active
+slot one token —
 the maxtext-style admission loop, so short requests free their slot for
 the queue instead of waiting for the longest sequence in the batch.
 """
@@ -64,10 +65,14 @@ def _prefill_jit(cfg):
     ms per call on the host — far more than the whole decode).  Shared by
     ``generate`` and ``ContinuousBatcher`` so a batcher admission runs the
     exact compiled program a solo generate does (token-parity).  One
-    compile per (batch, prompt_len) shape."""
+    compile per (batch, prompt_len) shape.  Given the device counter
+    ``held`` it also returns ``held`` plus the token-expert pairs its held
+    experts computed (``_add_pairs``)."""
 
-    def prefill(p, toks):
-        return T.prefill(p, {"tokens": toks}, cfg)
+    def prefill(p, toks, held=None):
+        logits, cache, pairs = T.prefill_counted(p, {"tokens": toks}, cfg)
+        return (logits, cache) if held is None else (
+            logits, cache, _add_pairs(held, pairs.sum()))
 
     return jax.jit(prefill)
 
@@ -86,10 +91,24 @@ def _extend_jit(cfg, cache_len):
 
 def _decode_path(cfg) -> str:
     """Which batched decode ``_batched_step`` compiles for ``cfg``: in
-    place for a plain attention stack, else the vmap."""
+    place for a plain attention stack (``mla`` for the latent-attention
+    stack, ``inplace`` for K/V), else the vmap."""
     if cfg.is_encoder_decoder or cfg.is_hybrid or cfg.is_ssm_only:
         return "vmap"
-    return "inplace"
+    return "mla" if cfg.is_mla else "inplace"
+
+
+def _counts_held_pairs(cfg) -> bool:
+    """Whether ``cfg``'s MoE layers are the held-expert layer, whose
+    computed token-expert pairs the batcher counts on the device."""
+    return cfg.is_moe and cfg.moe_router == "sigmoid"
+
+
+def _add_pairs(held, n):
+    """The 64-bit device counter ``held`` — uint32 words (low, high) —
+    plus ``n`` (< 2**32) pairs, carrying into the high word."""
+    lo = held[0] + n.astype(jnp.uint32)
+    return jnp.stack([lo, held[1] + (lo < held[0]).astype(jnp.uint32)])
 
 
 @functools.lru_cache(maxsize=None)
@@ -97,31 +116,38 @@ def _batched_step(cfg):
     """Per-row decode: tok (B,1) int32, pos (B,) int32 — each row advances
     at its OWN absolute position (slots hold requests of different ages).
     Returns (next greedy token (B,1), pos + 1, cache); the cache argument
-    is donated, so the output cache takes over its buffers.
+    is donated, so the output cache takes over its buffers.  Given the
+    device counter ``held`` and the rows that hold a request (``live``,
+    (B,) bool) it also returns ``held`` plus the token-expert pairs the
+    held experts computed for those rows in the step.
 
     A plain attention stack runs ``decode_step_rows``, which writes each
-    row's new K/V into the resident cache in place.  Other families wrap
-    the scalar-position ``decode_step`` in a vmap over the batch axis
-    (axis 1 of the stacked (L, B, ...) cache leaves), re-adding the
-    size-1 batch dim inside."""
+    row's new K/V (or MLA latent) into the resident cache in place.  Other
+    families wrap the scalar-position ``decode_step`` in a vmap over the
+    batch axis (axis 1 of the stacked (L, B, ...) cache leaves), re-adding
+    the size-1 batch dim inside."""
 
     def one(params, tok, pos, c):
         c1 = jax.tree.map(lambda a: a[:, None], c)
         logits, c1 = T.decode_step(params, tok[None, :], pos, cfg, c1)
         return logits[0, -1], jax.tree.map(lambda a: a[:, 0], c1)
 
-    def step(params, toks, poss, cache):
-        if _decode_path(cfg) == "inplace":
-            logits, cache = T.decode_step_rows(params, toks, poss, cfg, cache)
-            logits = logits[:, -1]
-        else:
+    def step(params, toks, poss, cache, held=None, live=None):
+        pairs = None
+        if _decode_path(cfg) == "vmap":
             logits, cache = jax.vmap(one, in_axes=(None, 0, 0, 1),
                                      out_axes=(0, 1))(params, toks, poss,
                                                       cache)
+        else:
+            logits, cache, pairs = T.decode_step_rows(params, toks, poss, cfg,
+                                                      cache)
+            logits = logits[:, -1]
         # pos advances for every slot on-device; a free slot harmlessly
         # decodes garbage past its request until it is re-admitted
-        return (logits.argmax(-1).astype(jnp.int32)[:, None], poss + 1,
-                cache)
+        out = (logits.argmax(-1).astype(jnp.int32)[:, None], poss + 1, cache)
+        if held is None:
+            return out
+        return out + (_add_pairs(held, jnp.where(live, pairs, 0).sum()),)
 
     return jax.jit(step, donate_argnums=3)
 
@@ -207,6 +233,10 @@ class ContinuousBatcher:
         self._results: Dict[int, List[int]] = {}  # materialized on demand
         self._submitted: Dict[int, float] = {}   # rid -> submit time (s)
         self.steps = 0                           # decode steps taken
+        # token-expert pairs the held experts computed for requests, on
+        # the device (``_add_pairs``)
+        self._held = (jnp.zeros(2, jnp.uint32) if _counts_held_pairs(cfg)
+                      else None)
 
     # -- request intake --------------------------------------------------
     def submit(self, prompt: np.ndarray, gen: int) -> int:
@@ -240,6 +270,16 @@ class ContinuousBatcher:
     def pending(self) -> bool:
         return bool(self._queue) or any(r >= 0 for r in self._rid)
 
+    def held_pairs(self) -> Optional[int]:
+        """Token-expert pairs the held experts have computed for requests
+        in this batcher's prefills and decode steps (the rows of free
+        slots left out), or None for a model without a held-expert layer.
+        Waits for the device."""
+        if self._held is None:
+            return None
+        lo, hi = np.asarray(self._held).tolist()
+        return hi << 32 | lo
+
     # -- the loop --------------------------------------------------------
     def _admit(self) -> List[int]:
         """Fill free slots from the queue.  Returns rids that completed
@@ -261,8 +301,12 @@ class ContinuousBatcher:
         """Prefill request ``rid`` into slot ``s``; True if it completed
         at admission (its prefill token is the whole answer)."""
         with spans.span("serve.prefill", rid=rid, program="jit_prefill"):
-            logits, one = _prefill_jit(self.cfg)(
-                self.params, jnp.asarray(prompt[None, :]))
+            toks = jnp.asarray(prompt[None, :])
+            if self._held is None:
+                logits, one = _prefill_jit(self.cfg)(self.params, toks)
+            else:
+                logits, one, self._held = _prefill_jit(self.cfg)(
+                    self.params, toks, self._held)
         with spans.span("serve.prefill", rid=rid, program="jit_extend_cache"):
             one = _extend_jit(self.cfg, self.cache_len)(one)
         # the host waits here for the prefill: the request's first token
@@ -300,8 +344,14 @@ class ContinuousBatcher:
             return done
         with spans.span("serve.decode", step=self.steps,
                         path=_decode_path(self.cfg)):
-            self._tok, self._pos, self._cache = _batched_step(self.cfg)(
-                self.params, self._tok, self._pos, self._cache)
+            step = _batched_step(self.cfg)
+            if self._held is None:
+                self._tok, self._pos, self._cache = step(
+                    self.params, self._tok, self._pos, self._cache)
+            else:
+                self._tok, self._pos, self._cache, self._held = step(
+                    self.params, self._tok, self._pos, self._cache,
+                    self._held, np.asarray(self._rid) >= 0)
         self._trace.append(self._tok)
         k = self.steps
         self.steps += 1

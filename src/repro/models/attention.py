@@ -1,4 +1,5 @@
-"""GQA attention: train/prefill (flash-chunked), encoder (full), cross, decode.
+"""GQA attention: train/prefill (flash-chunked), encoder (full), cross, decode;
+and multi-head latent attention (MLA): plain prefill, absorbed decode.
 
 Pure JAX. Query-chunked + kv-chunked online-softmax attention keeps live
 memory bounded at 32k sequence lengths; causal chunk skipping is structural
@@ -140,11 +141,11 @@ def _flash_attention(q, k, v, *, causal: bool, window: int = 0,
 
         m0 = jnp.full((B, G, rep, q_chunk), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, G, rep, q_chunk), jnp.float32)
-        a0 = jnp.zeros((B, G, rep, q_chunk, hd), jnp.float32)
+        a0 = jnp.zeros((B, G, rep, q_chunk, v.shape[-1]), jnp.float32)
         (m, l, acc), _ = jax.lax.scan(body, (m0, l0, a0),
                                       jnp.arange(n_kv))
-        o = acc / jnp.maximum(l, 1e-30)[..., None]        # (B,G,rep,qc,hd)
-        return jnp.moveaxis(o, 3, 1).reshape(B, q_chunk, H, hd).astype(q.dtype)
+        o = acc / jnp.maximum(l, 1e-30)[..., None]        # (B,G,rep,qc,hd_v)
+        return jnp.moveaxis(o, 3, 1).reshape(B, q_chunk, H, -1).astype(q.dtype)
 
     outs = []
     for qi in range(n_q):
@@ -370,3 +371,116 @@ def attn_decode_seqshard(p, x, pos, cfg, cache) -> Tuple[jax.Array, dict]:
     new_cache = dict(cache, k=k2, v=v2)
     o = o.reshape(B, 1, -1) @ p["wo"]
     return shard(o, "batch", "seq", "d_model"), new_cache
+
+
+# -- multi-head latent attention (MLA, DeepSeek-V2/V3) ---------------------
+# Queries: x @ wq -> per head a part without rope (qk_nope_dim) and a rope
+# part (qk_rope_dim).  Keys and values come from a latent: x @ wkv_a gives
+# the latent (kv_lora_rank, RMS-normed by kv_norm) and one rope key shared
+# by every head; the latent @ wkv_b gives each head's key part without rope
+# and its value.  Softmax scale 1 / sqrt(qk_nope_dim + qk_rope_dim).  The
+# cache holds the normed latent and the roped shared key of each position.
+def mla_init(key, cfg, dtype=jnp.float32):
+    H, r = cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    k1, k2, k3, k4 = jax.random.split(key, 4)
+    return {
+        "wq": dense_init(k1, cfg.d_model, H * (dn + dr), dtype),
+        "wkv_a": dense_init(k2, cfg.d_model, r + dr, dtype),
+        "kv_norm": jnp.ones((r,), dtype),
+        "wkv_b": dense_init(k3, r, H * (dn + dv), dtype),
+        "wo": dense_init(k4, H * dv, cfg.d_model, dtype),
+    }
+
+
+def rope_pairs(x, positions, theta: float):
+    """Rotary embedding of interleaved pairs (x[2i], x[2i+1]), as the
+    deepseek_v3 modeling code applies it: the pairs are gathered into
+    halves (evens, then odds) before the rotate-half rotation, and the
+    result stays in that order.  Queries and keys take the same order, so
+    their products are those of the interleaved rotation."""
+    x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+    return rotary(x, positions, theta)
+
+
+def _mla_q(p, x, positions, cfg):
+    """-> (q without rope (B,S,H,dn), roped q (B,S,H,dr))."""
+    B, S, _ = x.shape
+    dn = cfg.qk_nope_dim
+    q = (x @ p["wq"]).reshape(B, S, cfg.n_heads, dn + cfg.qk_rope_dim)
+    return q[..., :dn], rope_pairs(q[..., dn:], positions, cfg.rope_theta)
+
+
+def _mla_latent(p, x, positions, cfg):
+    """The rows the cache holds: normed latent (B,S,r), roped key (B,S,dr)."""
+    r = cfg.kv_lora_rank
+    kv = x @ p["wkv_a"]
+    c = head_rmsnorm(kv[..., :r], p["kv_norm"], cfg.norm_eps)
+    k_pe = rope_pairs(kv[..., None, r:], positions, cfg.rope_theta)[..., 0, :]
+    return c, k_pe
+
+
+def mla_forward(p, x, positions, cfg, *, flash_threshold: int = 2048,
+                return_cache: bool = False):
+    """Full-sequence causal MLA (train / prefill): the latent is expanded
+    into each head's keys and values.  With ``return_cache`` also returns
+    the rows the cache holds, ``{c_kv: (B,S,r), k_pe: (B,S,dr)}``."""
+    with jax.named_scope("mla_attn"):
+        B, S, _ = x.shape
+        H, dn, dr = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
+        q_nope, q_pe = _mla_q(p, x, positions, cfg)
+        c, k_pe = _mla_latent(p, x, positions, cfg)
+        kv = (c @ p["wkv_b"]).reshape(B, S, H, dn + cfg.v_head_dim)
+        q = jnp.concatenate([q_nope, q_pe], axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(k_pe[:, :, None], (B, S, H, dr))],
+            axis=-1)
+        v = kv[..., dn:]
+        if S > flash_threshold:
+            o = _flash_attention(q, k, v, causal=True)
+        else:
+            o = _plain_attention(q, k, v, jnp.tril(jnp.ones((S, S), bool)))
+        o = o.reshape(B, S, -1) @ p["wo"]
+    if return_cache:
+        return o, {"c_kv": c, "k_pe": k_pe}
+    return o
+
+
+def mla_decode_rows(p, x, pos, cfg, c_all, pe_all, layer
+                    ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """One-token MLA with each row at its own position, against layer
+    ``layer`` of the stacked latent cache ``c_all`` (L, B, S, r) and rope
+    keys ``pe_all`` (L, B, S, dr).  x: (B,1,D); pos: (B,) int32.  Writes
+    only the B new rows (a row whose position is past the cache writes
+    nothing).  Absorbed: wkv_b's key half is folded into the query and its
+    value half into the output, so attention runs over the cached rows as
+    they are.  Same math as ``mla_forward`` per row."""
+    with jax.named_scope("mla_attn"):
+        B = x.shape[0]
+        H, r = cfg.n_heads, cfg.kv_lora_rank
+        dn, dr = cfg.qk_nope_dim, cfg.qk_rope_dim
+        positions = pos[:, None]
+        q_nope, q_pe = _mla_q(p, x, positions, cfg)
+        c, k_pe = _mla_latent(p, x, positions, cfg)
+        rows = jnp.arange(B)
+        c_all = c_all.at[layer, rows, pos].set(c[:, 0].astype(c_all.dtype),
+                                               mode="drop")
+        pe_all = pe_all.at[layer, rows, pos].set(
+            k_pe[:, 0].astype(pe_all.dtype), mode="drop")
+        c_l, pe_l = c_all[layer], pe_all[layer]                  # (B,S,.)
+        w = p["wkv_b"].reshape(r, H, dn + cfg.v_head_dim)
+        f32 = jnp.float32
+        q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], w[..., :dn],
+                           preferred_element_type=f32).astype(c_l.dtype)
+        s = (jnp.einsum("bhr,bsr->bhs", q_lat, c_l, preferred_element_type=f32)
+             + jnp.einsum("bhd,bsd->bhs", q_pe[:, 0].astype(pe_l.dtype), pe_l,
+                          preferred_element_type=f32)) / math.sqrt(dn + dr)
+        mask = jnp.arange(c_l.shape[1])[None, :] <= positions       # (B,S)
+        s = jnp.where(mask[:, None, :], s, NEG_INF)
+        probs = jax.nn.softmax(s, axis=-1)
+        o_lat = jnp.einsum("bhs,bsr->bhr", probs.astype(c_l.dtype), c_l,
+                           preferred_element_type=f32).astype(x.dtype)
+        o = jnp.einsum("bhr,rhv->bhv", o_lat, w[..., dn:],
+                       preferred_element_type=f32).astype(x.dtype)
+        o = o.reshape(B, 1, -1) @ p["wo"]
+    return o, c_all, pe_all

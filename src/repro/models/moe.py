@@ -14,6 +14,14 @@ Two execution paths with identical routing semantics:
 
 Top-k routing: softmax over the top-k router logits (Mixtral convention).
 Aux output is the Switch-style load-balance loss.
+
+``held_moe_apply`` is the DeepSeek-V3 layer (``moe_router="sigmoid"``):
+sigmoid scores with a per-expert bias for selection only, shared experts
+on every token, and only the routed experts ``cfg.held_experts`` computed
+here — a chip's share under expert parallelism, run without the exchange.
+It routes over every expert, drops no token, and its expert work grows
+with the pairs routed to the experts it holds (grouped matmuls over the
+pairs sorted by expert), not with experts x tokens.
 """
 from __future__ import annotations
 
@@ -24,7 +32,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import dense_init
+from repro.models.layers import dense_init, mlp, mlp_init
 from repro.sharding.rules import active_rules, shard, shard_map
 
 
@@ -163,3 +171,76 @@ def moe_apply(params, x, cfg) -> Tuple[jax.Array, jax.Array]:
     y, aux = shard_map(body, mesh=mesh, in_specs=in_specs,
                            out_specs=out_specs, check_vma=False)(params, xt)
     return shard(y.reshape(B, S, D), "batch", "seq", "d_model"), aux
+
+
+# -- the held-expert layer (DeepSeek-V3 routing, shared experts) ----------
+def held_moe_init(key, cfg, dtype=jnp.float32):
+    d, f, E = cfg.d_model, cfg.expert_d_ff, cfg.n_experts
+    lo, hi = cfg.held_experts
+    k1, k2, k3, k4, k5 = jax.random.split(key, 5)
+    scale = 1.0 / math.sqrt(d)
+    p = {
+        "router": dense_init(k1, d, E, jnp.float32),
+        "router_bias": jnp.zeros((E,), jnp.float32),
+        "e_gate": jax.random.uniform(k2, (hi - lo, d, f), dtype, -scale, scale),
+        "e_up": jax.random.uniform(k3, (hi - lo, d, f), dtype, -scale, scale),
+        "e_down": jax.random.uniform(k4, (hi - lo, f, d), dtype,
+                                     -1.0 / math.sqrt(f), 1.0 / math.sqrt(f)),
+    }
+    if cfg.n_shared_experts:
+        p["shared"] = mlp_init(k5, d, cfg.n_shared_experts * f, dtype)
+    return p
+
+
+def route_sigmoid(router, bias, x, k: int, scaling: float):
+    """DeepSeek-V3 ``noaux_tc`` routing with one group.  x: (T, D) ->
+    (weights (T,k) f32, experts (T,k) i32): the k experts of the largest
+    sigmoid score + ``bias``, weighted by their scores without the bias,
+    normalised to sum 1 and times ``scaling``.  The logits are float32 at
+    full precision, as the published model computes them."""
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, top_e = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+    w = jnp.take_along_axis(scores, top_e, axis=-1)
+    return w / (w.sum(-1, keepdims=True) + 1e-20) * scaling, top_e
+
+
+def held_moe_apply(params, x, cfg) -> Tuple[jax.Array, jax.Array]:
+    """x: (B, S, D) -> (y (B,S,D), the token-expert pairs the held experts
+    computed for each token (B,S) int32).  y is the held experts' weighted
+    part of the routed result plus the shared experts."""
+    B, S, D = x.shape
+    xt = x.reshape(B * S, D)
+    k = cfg.moe_top_k
+    lo, hi = cfg.held_experts
+    f32 = jnp.float32
+    with jax.named_scope("moe_route"):
+        w, e = route_sigmoid(params["router"], params["router_bias"], xt, k,
+                             cfg.routed_scaling)
+        local = e.reshape(-1) - lo                               # (T*k,)
+        held = (local >= 0) & (local < hi - lo)
+        group = jnp.where(held, local, hi - lo)   # the pairs not held last
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.sum(group[:, None] == jnp.arange(hi - lo)[None, :],
+                        axis=0).astype(jnp.int32)
+        tok = order // k
+        keep = held[order]
+        wk = jnp.where(keep, w.reshape(-1)[order], 0.0)
+        xs = xt[tok]
+    with jax.named_scope("moe_experts"):
+        g = jax.lax.ragged_dot(xs, params["e_gate"], sizes,
+                               preferred_element_type=f32)
+        u = jax.lax.ragged_dot(xs, params["e_up"], sizes,
+                               preferred_element_type=f32)
+        h = (jax.nn.silu(g) * u).astype(x.dtype)
+        out = jax.lax.ragged_dot(h, params["e_down"], sizes,
+                                 preferred_element_type=f32)
+        # rows past the held pairs belong to no group: not read
+        out = jnp.where(keep[:, None], out, 0.0) * wk[:, None]
+        y = jnp.zeros((B * S, D), f32).at[tok].add(out)
+    if "shared" in params:
+        with jax.named_scope("moe_shared"):
+            y = y + mlp(params["shared"], x).reshape(B * S, D).astype(f32)
+    pairs = held.reshape(B, S, k).sum(-1, dtype=jnp.int32)
+    return y.astype(x.dtype).reshape(B, S, D), pairs

@@ -1,4 +1,5 @@
-"""Architecture assembly: dense / MoE / SSM / hybrid decoders, enc-dec, VLM.
+"""Architecture assembly: dense / MoE / SSM / hybrid decoders, enc-dec, VLM,
+and the latent-attention (MLA) stack of DeepSeek-V3-style models.
 
 All stacks use ``lax.scan`` over stacked layer parameters so the HLO stays
 small at 88 layers.  The hybrid (Jamba) stack scans over *groups* of
@@ -10,8 +11,11 @@ Public entry points:
   forward(params, batch, cfg)             -> (logits, aux)   # train / prefill
   init_decode_state(cfg, batch, cache_len, dtype, rolling)   -> cache pytree
   decode_step(params, tokens, pos, cfg, cache)  -> (logits, new cache)
-  decode_step_rows(params, tokens, pos, cfg, cache) -> (logits, new cache)
+  decode_step_rows(params, tokens, pos, cfg, cache)
+                                          -> (logits, new cache, held pairs)
                                           # per-row positions, in place
+  prefill_counted(params, batch, cfg)     -> (logits, cache, held pairs)
+                                          # held pairs: (B,) int32 per row
   lm_loss(params, batch, cfg)             -> (loss, aux)
 """
 from __future__ import annotations
@@ -93,6 +97,23 @@ def _init_encdec_layer(key, cfg, dtype, decoder: bool):
     return p
 
 
+def _init_mla_layer(key, cfg, dtype, moe: bool):
+    """One layer of the MLA stack: MLA, then a dense SwiGLU FFN or the
+    held-expert MoE (``moe_router="sigmoid"``)."""
+    if moe and cfg.moe_router != "sigmoid":
+        raise ValueError("the MLA stack's MoE layers route as DeepSeek-V3 "
+                         "does: moe_router must be 'sigmoid'")
+    k1, k2 = jax.random.split(key)
+    p = {"norm1": rmsnorm_init(cfg.d_model, dtype),
+         "attn": attn.mla_init(k1, cfg, dtype),
+         "norm2": rmsnorm_init(cfg.d_model, dtype)}
+    if moe:
+        p["moe"] = moe_mod.held_moe_init(k2, cfg, dtype)
+    else:
+        p["ffn"] = mlp_init(k2, cfg.d_model, cfg.d_ff, dtype)
+    return p
+
+
 def init_model(key, cfg, dtype=jnp.float32) -> Dict[str, Any]:
     ke, kl, kh, kp = jax.random.split(key, 4)
     params: Dict[str, Any] = {
@@ -115,6 +136,14 @@ def init_model(key, cfg, dtype=jnp.float32) -> Dict[str, Any]:
         gkeys = jax.random.split(kl, n_groups)
         params["layers"] = jax.vmap(
             lambda k: _init_hybrid_group(k, cfg, dtype))(gkeys)
+    elif cfg.is_mla:
+        k = cfg.first_k_dense
+        lkeys = jax.random.split(kl, cfg.n_layers)
+        if k:
+            params["dense_layers"] = jax.vmap(
+                lambda kk: _init_mla_layer(kk, cfg, dtype, moe=False))(lkeys[:k])
+        params["layers"] = jax.vmap(
+            lambda kk: _init_mla_layer(kk, cfg, dtype, moe=cfg.is_moe))(lkeys[k:])
     else:
         lkeys = jax.random.split(kl, cfg.n_layers)
         params["layers"] = jax.vmap(
@@ -191,8 +220,66 @@ def _hybrid_group_block(x, gp, cfg, positions, window, collect_cache=False):
     return x, aux, kv
 
 
+def _mla_ffn(x, lp, cfg):
+    """The FFN half of an MLA-stack layer on the residual stream x: the
+    held-expert MoE, or layer 0's dense SwiGLU.  -> (x + FFN, the pairs its
+    held experts computed per row (B,))."""
+    h = rmsnorm(lp["norm2"], x, cfg.norm_eps)
+    if "moe" in lp:
+        y, pairs = moe_mod.held_moe_apply(lp["moe"], h, cfg)
+        return x + y, pairs.sum(-1)
+    with jax.named_scope("dense_ffn"):
+        return x + mlp(lp["ffn"], h), jnp.zeros(x.shape[:1], jnp.int32)
+
+
+def _mla_block(x, lp, cfg, positions, collect_cache=False):
+    kv = None
+    h = rmsnorm(lp["norm1"], x, cfg.norm_eps)
+    o = attn.mla_forward(lp["attn"], h, positions, cfg,
+                         return_cache=collect_cache)
+    if collect_cache:
+        o, kv = o
+    x, pairs = _mla_ffn(x + o, lp, cfg)
+    return x, pairs, kv
+
+
+# the MLA stack's parameter groups, each under its own layer loop
+_MLA_GROUPS = ("dense_layers", "layers")
+
+
+def _run_mla_stack(params, x, cfg, positions, collect_cache=False,
+                   remat=False):
+    """The leading dense layers under one scan, then the MoE layers under
+    another.  Returns (x, token-expert pairs the held experts computed per
+    row (B,), the stacked cache rows of all layers or None)."""
+    pairs, caches = jnp.zeros(x.shape[:1], jnp.int32), []
+    for name in _MLA_GROUPS:
+        if name not in params:
+            continue
+        block = partial(_mla_block, cfg=cfg, positions=positions,
+                        collect_cache=collect_cache)
+        if remat:
+            block = jax.checkpoint(block)
+
+        def body(carry, lp, block=block):
+            x, n = carry
+            x, m, kv = block(x, lp)
+            return (x, n + m), kv
+
+        (x, pairs), kv = jax.lax.scan(body, (x, pairs), params[name])
+        caches.append(kv)
+    cache = None
+    if collect_cache:
+        cache = jax.tree.map(lambda *a: jnp.concatenate(a), *caches)
+    return x, pairs, cache
+
+
 def _run_stack(params, x, cfg, positions, window=0, collect_cache=False,
                remat=False):
+    if cfg.is_mla:
+        x, _, caches = _run_mla_stack(params, x, cfg, positions,
+                                      collect_cache, remat)
+        return x, jnp.float32(0.0), caches
     if cfg.is_hybrid:
         block = partial(_hybrid_group_block, cfg=cfg, positions=positions,
                         window=window, collect_cache=collect_cache)
@@ -274,6 +361,14 @@ def prefill(params, batch: Dict[str, jax.Array], cfg, window: int = 0
             ) -> Tuple[jax.Array, Any]:
     """Serve-side prefill: process the full prompt, return (last-position
     logits, layer-stacked KV/SSM cache) ready for ``decode_step``."""
+    logits, cache, _ = prefill_counted(params, batch, cfg, window)
+    return logits, cache
+
+
+def prefill_counted(params, batch: Dict[str, jax.Array], cfg, window: int = 0
+                    ) -> Tuple[jax.Array, Any, jax.Array]:
+    """``prefill``, and the token-expert pairs its held experts computed
+    per row ((B,) int32; zeros for a model without a held-expert layer)."""
     if cfg.is_encoder_decoder:
         raise NotImplementedError("use encdec_prefill for encoder-decoder")
     tokens = batch["tokens"]
@@ -283,12 +378,17 @@ def prefill(params, batch: Dict[str, jax.Array], cfg, window: int = 0
         pe = batch["patches"] @ params["patch_proj"]
         x = jnp.concatenate([pe.astype(x.dtype), x], axis=1)
     positions = jnp.broadcast_to(jnp.arange(x.shape[1]), x.shape[:2])
-    x, _, cache = _run_stack(params, x, cfg, positions, window,
-                             collect_cache=True)
+    if cfg.is_mla:
+        x, pairs, cache = _run_mla_stack(params, x, cfg, positions,
+                                         collect_cache=True)
+    else:
+        x, _, cache = _run_stack(params, x, cfg, positions, window,
+                                 collect_cache=True)
+        pairs = jnp.zeros(x.shape[:1], jnp.int32)
     x = rmsnorm(params["final_norm"], x[:, -1:, :], cfg.norm_eps)
     logits = lm_head(x, params["embed"] if cfg.tie_embeddings else None,
                      params.get("lm_head"))
-    return logits, cache
+    return logits, cache, pairs
 
 
 def encdec_prefill(params, batch: Dict[str, jax.Array], cfg,
@@ -389,16 +489,21 @@ def lm_loss(params, batch, cfg, window: int = 0,
     return loss + lb_weight * aux, {"nll": loss, "lb": aux}
 
 
+# stacked cache leaves whose axis 2 is the sequence: name -> ndim
+_SEQ_LEAVES = {"k": 5, "v": 5, "c_kv": 4, "k_pe": 4}
+
+
 def extend_cache(cache, target_len: int):
-    """Pad the sequence axis of attention KV caches (stacked layout
-    (L, B, S, Hkv, hd)) out to ``target_len`` slots for continued decode."""
+    """Pad the sequence axis of attention caches (stacked layouts
+    (L, B, S, Hkv, hd) for K/V, (L, B, S, width) for MLA's latent and rope
+    key) out to ``target_len`` slots for continued decode."""
 
     def pad(path, a):
         name = None
         for p in path:
             if hasattr(p, "key"):
                 name = str(p.key)
-        if name in ("k", "v") and a.ndim == 5 and a.shape[2] < target_len:
+        if _SEQ_LEAVES.get(name) == a.ndim and a.shape[2] < target_len:
             padw = [(0, 0)] * a.ndim
             padw[2] = (0, target_len - a.shape[2])
             return jnp.pad(a, padw)
@@ -413,6 +518,12 @@ def extend_cache(cache, target_len: int):
 def init_decode_state(cfg, batch: int, cache_len: int, dtype=jnp.bfloat16,
                       rolling: bool = False, quantized: bool = False):
     """Stacked (over layers / groups) cache pytree."""
+    if cfg.is_mla:
+        if rolling or quantized:
+            raise NotImplementedError("MLA keeps a plain latent cache")
+        L = cfg.n_layers
+        return {"c_kv": jnp.zeros((L, batch, cache_len, cfg.kv_lora_rank), dtype),
+                "k_pe": jnp.zeros((L, batch, cache_len, cfg.qk_rope_dim), dtype)}
     if cfg.is_encoder_decoder:
         one = attn.init_cache(cfg, batch, cache_len, dtype,
                               cross_len=cfg.enc_seq, quantized=quantized)
@@ -438,6 +549,12 @@ def _stack_tree(tree, n: int):
 def decode_step(params, tokens, pos, cfg, cache, *, rolling: bool = False,
                 seq_shard_kv: bool = False) -> Tuple[jax.Array, Any]:
     """tokens: (B, 1) int32; pos: scalar int32 absolute position."""
+    if cfg.is_mla:
+        if rolling or seq_shard_kv:
+            raise NotImplementedError("MLA decodes a plain latent cache")
+        rows = jnp.full((tokens.shape[0],), pos, jnp.int32)
+        logits, cache, _ = decode_step_rows(params, tokens, rows, cfg, cache)
+        return logits, cache
     x = embed_lookup(params["embed"], tokens)
     x = shard(x, "batch", "seq", "d_model")
     aux = jnp.float32(0.0)
@@ -516,14 +633,21 @@ def decode_step(params, tokens, pos, cfg, cache, *, rolling: bool = False,
     return logits, new_cache
 
 
-def decode_step_rows(params, tokens, pos, cfg, cache) -> Tuple[jax.Array, Any]:
+def decode_step_rows(params, tokens, pos, cfg, cache
+                     ) -> Tuple[jax.Array, Any, jax.Array]:
     """tokens: (B, 1) int32; pos: (B,) int32, each row at its own absolute
     position.  For a plain attention stack (dense or MoE FFN; not
-    encoder-decoder, hybrid or SSM) with an unquantized {k, v} cache.
+    encoder-decoder, hybrid or SSM) with an unquantized {k, v} cache, or
+    the MLA stack with its {c_kv, k_pe} cache.
 
-    The stacked (L, B, S, G, hd) K and V ride the layer loop's carry, so
+    The stacked (L, B, S, ...) cache leaves ride the layer loop's carry, so
     each layer writes only its B new rows; under a donating jit the cache
-    is updated in place.  Per row, the same math as ``decode_step``."""
+    is updated in place.  Per row, the same math as ``decode_step``.
+    Returns (logits, cache, the token-expert pairs the held experts
+    computed per row: (B,) int32, zeros for a model without a held-expert
+    layer)."""
+    if cfg.is_mla:
+        return _decode_rows_mla(params, tokens, pos, cfg, cache)
     x = embed_lookup(params["embed"], tokens)
     x = shard(x, "batch", "seq", "d_model")
 
@@ -546,4 +670,36 @@ def decode_step_rows(params, tokens, pos, cfg, cache) -> Tuple[jax.Array, Any]:
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = lm_head(x, params["embed"] if cfg.tie_embeddings else None,
                      params.get("lm_head"))
-    return logits, dict(cache, k=k, v=v)
+    return logits, dict(cache, k=k, v=v), jnp.zeros(tokens.shape[:1],
+                                                    jnp.int32)
+
+
+def _decode_rows_mla(params, tokens, pos, cfg, cache):
+    """``decode_step_rows`` of the MLA stack: the leading dense layers' loop,
+    then the MoE layers', both carrying the whole stacked latent cache and
+    writing their layers' rows in place (absorbed MLA)."""
+    x = embed_lookup(params["embed"], tokens)
+    carry = (x, cache["c_kv"], cache["k_pe"],
+             jnp.zeros(tokens.shape[:1], jnp.int32))
+
+    def body(carry, xs):
+        x, c, pe, pairs = carry
+        lp, l = xs
+        h = rmsnorm(lp["norm1"], x, cfg.norm_eps)
+        o, c, pe = attn.mla_decode_rows(lp["attn"], h, pos, cfg, c, pe, l)
+        x, n = _mla_ffn(x + o, lp, cfg)
+        return (x, c, pe, pairs + n), None
+
+    first = 0
+    for name in _MLA_GROUPS:
+        if name not in params:
+            continue
+        n_layers = jax.tree.leaves(params[name])[0].shape[0]
+        carry, _ = jax.lax.scan(body, carry,
+                                (params[name], first + jnp.arange(n_layers)))
+        first += n_layers
+    x, c, pe, pairs = carry
+    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+    logits = lm_head(x, params["embed"] if cfg.tie_embeddings else None,
+                     params.get("lm_head"))
+    return logits, dict(cache, c_kv=c, k_pe=pe), pairs

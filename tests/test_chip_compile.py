@@ -136,6 +136,35 @@ def test_batched_decode_updates_the_cache_in_place(one_chip):
     assert compiled.as_text().startswith("HloModule jit_step,")
 
 
+def test_moonlight_decode_updates_the_latent_cache_in_place(one_chip):
+    """Moonlight-16B-A3B on one chip's share (experts 0-7 of 64) at its
+    cell's 64 slots of 1536 positions: the output latent cache aliases the
+    donated input, the step needs no temporary as large as one layer's
+    latent slab, and the held experts run as grouped matmuls."""
+    cfg = get_config("moonshot_v1_16b")
+    B, S = 64, 1536
+    params = jax.tree.map(
+        lambda a: _spec(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda: T.init_model(jax.random.PRNGKey(0), cfg,
+                                            jnp.bfloat16)))
+    cache = {"c_kv": _spec(one_chip, (cfg.n_layers, B, S, cfg.kv_lora_rank),
+                           jnp.bfloat16),
+             "k_pe": _spec(one_chip, (cfg.n_layers, B, S, cfg.qk_rope_dim),
+                           jnp.bfloat16)}
+    compiled = serve._batched_step(cfg).lower(
+        params, _spec(one_chip, (B, 1), jnp.int32),
+        _spec(one_chip, (B,), jnp.int32), cache,
+        _spec(one_chip, (2,), jnp.uint32),
+        _spec(one_chip, (B,), jnp.bool_)).compile()
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(math.prod(c.shape) * 2 for c in cache.values())
+    slab_bytes = B * S * cfg.kv_lora_rank * 2
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < slab_bytes
+    assert compiled.as_text().startswith("HloModule jit_step,")
+    assert _has_kernel(compiled)
+
+
 def test_chip_smoke_refuses_cpu():
     """Without a TPU the smoke script exits non-zero and prints no result."""
     r = subprocess.run([sys.executable, os.path.join(REPO, "chip_smoke.py")],
