@@ -60,7 +60,8 @@ from __future__ import annotations
 import dataclasses
 import functools
 import heapq
-from typing import Any, Dict, List, Optional, Tuple
+from collections import deque
+from typing import Any, Deque, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -465,7 +466,19 @@ class CohortTrainer:
     cohorts train in one jitted call (padded to power-of-two buckets so jit's
     shape cache stays small).  Device data is pre-stacked once; minibatch
     permutations come from a dedicated RNG (the deferred path makes no
-    bit-parity promise, only distributional equivalence)."""
+    bit-parity promise, only distributional equivalence).
+
+    A flush does not wait for its rounds: it launches each round, starts
+    the copy of its result to the host and queues the round in
+    ``in_flight``, then returns.  A round's result is collected when it is
+    first asked for (``result``); the end of an engine's ``run`` and a
+    checkpoint collect every round.  Rounds are collected in launch order,
+    so asking for one collects every older round first.  Host work between
+    a grant and its arrival thus overlaps the device's round.
+    ``rounds_collected`` counts the rounds collected and ``rounds_ready``
+    those that had finished on the device before they were collected; both
+    depend on timing, so they stay out of the engine's stats and its
+    checkpoints."""
 
     deferred = True
 
@@ -477,6 +490,12 @@ class CohortTrainer:
         self.perm_rng = np.random.RandomState(engine.cfg.seed + 0x9E3779)
         self._serial = SerialTrainer(engine)   # sync-loop fallback
         self.pending: List[PendingTask] = []
+        # launched rounds not yet collected, oldest first:
+        # (program, device result, [(task, row of the result)])
+        self.in_flight: Deque[Tuple[str, Any, List[Tuple[PendingTask,
+                                                         int]]]] = deque()
+        self.rounds_collected = 0
+        self.rounds_ready = 0
         self._versions: List[Any] = []
         self._version_ids: Dict[int, int] = {}
         parts = engine.partitions
@@ -528,9 +547,23 @@ class CohortTrainer:
 
     def result(self, task: PendingTask) -> Tuple[Any, int]:
         if task.result is None:
-            self.flush()
+            if any(p is task for p in self.pending):
+                self.flush()
+            self.collect(task)
         assert task.result is not None
         return task.result
+
+    def collect(self, task: Optional[PendingTask] = None) -> None:
+        """Collect launched rounds, oldest first: up to and including
+        ``task``'s round, or every round in flight without ``task``."""
+        while self.in_flight and (task is None or task.result is None):
+            program, out, rows = self.in_flight.popleft()
+            ready = all(a.is_ready() for a in jax.tree.leaves(out))
+            out_np = self._to_host(out, program, ready)
+            for t, i in rows:
+                t.result = (jax.tree.map(lambda a, i=i: a[i], out_np), t.n_k)
+            self.rounds_collected += 1
+            self.rounds_ready += ready
 
     @staticmethod
     def _pad_pow2(n: int) -> int:
@@ -582,10 +615,7 @@ class CohortTrainer:
             with spans.span("fl.flush.launch", program=program):
                 w_up_v = _zero_step_round(w_versions, p_s=p_s, p_q=p_q,
                                           iters=self.channel_iters)
-            w_np = self._to_host(w_up_v, program)
-            for t in group:
-                t.result = (jax.tree.map(lambda a, v=t.version: a[v], w_np),
-                            t.n_k)
+            self._launched(program, w_up_v, [(t, t.version) for t in group])
             return
         bs = cfg.batch_size
         bidx = np.zeros((c_pad, t_max, bs), np.int32)
@@ -612,18 +642,27 @@ class CohortTrainer:
                 valid_d, cohort_loss=self.engine.task.cohort_loss,
                 lr=cfg.lr, mu=cfg.mu, p_s=p_s, p_q=p_q,
                 iters=self.channel_iters)
-        w_up_np = self._to_host(w_up, program)
-        for i, t in enumerate(group):
-            t.result = (jax.tree.map(lambda a, i=i: a[i], w_up_np), t.n_k)
+        self._launched(program, w_up, [(t, i) for i, t in enumerate(group)])
+
+    def _launched(self, program: str, out: Any,
+                  rows: List[Tuple[PendingTask, int]]) -> None:
+        """Start ``out``'s copy to the host and queue it for ``collect``."""
+        for a in jax.tree.leaves(out):
+            a.copy_to_host_async()
+        self.in_flight.append((program, out, rows))
 
     @staticmethod
-    def _to_host(tree: Any, program: str) -> Any:
+    def _to_host(tree: Any, program: str, ready: bool) -> Any:
         """``tree`` (the result of ``program``) as numpy: one bulk
         device->host transfer per leaf, so that per-task results are free
         numpy views (a per-task jnp slice costs an eager dispatch, which
-        dominated the flush at large N).  The wait for the device and the
-        copy are spans of their own."""
-        with spans.span("fl.flush.wait", program=program):
+        dominated the flush at large N).  ``collect`` calls it, after the
+        flush that launched the round has returned, one round at a time in
+        launch order; the transfer started at the launch, so the copy
+        usually reads bytes that have landed.  The wait for the device
+        (``ready``: whether the round had finished before it) and the copy
+        are spans of their own."""
+        with spans.span("fl.flush.wait", program=program, ready=int(ready)):
             jax.block_until_ready(tree)
         with spans.span("fl.flush.copy", nbytes=spans.nbytes(tree)
                         if spans.enabled() else 0):
@@ -762,7 +801,10 @@ class FLEngine:
         with spans.span("fl.run"):
             if not self.strategy.event_driven:
                 return self._run_sync(time_budget, max_rounds, eval_every)
-            return self._run_async(time_budget, max_rounds, eval_every)
+            history = self._run_async(time_budget, max_rounds, eval_every)
+            if self.trainer.deferred:
+                self.trainer.collect()   # no round of this call left in flight
+            return history
 
     # -- asynchronous event loop (Algs. 1-2) -------------------------------
     def _resume(self) -> None:
@@ -978,6 +1020,8 @@ class FLEngine:
         return self._unpack_tree(packed[1]), int(packed[2])
 
     def _pack_pending(self, reg) -> List[Any]:
+        if self.trainer.deferred:
+            self.trainer.collect()     # the rounds in flight hold results
         return [[int(p.k), int(p.version), int(p.t0), float(p.p_s),
                  int(p.p_q), int(p.n_k), np.asarray(p.bidx),
                  None if p.result is None

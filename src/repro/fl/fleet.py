@@ -302,6 +302,8 @@ class MultiTaskEngine:
     def _finish(self, now: float, time_budget: float) -> List[List[LogEntry]]:
         self._now = now
         for rt in self.runtimes:
+            if rt.trainer.deferred:
+                rt.trainer.collect()     # no round left in flight
             rt._log(min(now, time_budget))
             rt._tail_logged = True
         return [rt.history for rt in self.runtimes]

@@ -313,12 +313,13 @@ def test_blob_roundtrip(tmp_path):
 
 
 @pytest.mark.parametrize("scheduler", ["heap", "batched"])
-@pytest.mark.parametrize("cohort", [0, 4], ids=["serial", "cohort"])
+@pytest.mark.parametrize("cohort", [0, 4, 1],
+                         ids=["serial", "cohort", "cohort1"])
 def test_engine_checkpoint_resume_bit_parity(scheduler, cohort, tiny_setup,
                                              tmp_path):
     """run(2) -> state_dict -> save_blob -> fresh engine -> load -> run(4)
     must be bit-identical to an uninterrupted run(4), including the
-    deferred cohort buffer and every RNG stream."""
+    deferred cohort buffer, the rounds in flight and every RNG stream."""
     data, parts, w0 = tiny_setup
     cfg = tiny_spec("teasq", len(parts), epochs=1, seed=3,
                     scheduler=scheduler, cohort_size=cohort)

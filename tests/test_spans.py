@@ -84,13 +84,22 @@ def test_fl_spans_nest_and_count_bytes(fl_setup, cohort, tmp_path,
     by = {n: [s for s in found if s[0] == n] for n in FL_SPANS}
     for flush in by["repro.fl.flush"]:
         assert flush[3]["tasks"] >= 1
-        for part in ("stage", "launch", "wait", "copy"):
+        for part in ("stage", "launch"):
             assert any(_inside(s, flush) for s in by["repro.fl.flush." + part])
         # the index arrays at least go to the device
         assert sum(s[3]["nbytes"] for s in by["repro.fl.flush.stage"]
                    if _inside(s, flush)) > 0
-    for launch in by["repro.fl.flush.launch"] + by["repro.fl.flush.wait"]:
-        assert launch[3]["program"] == "jit__cohort_round"
+    # the flush returns before its round ends: each launch has one wait and
+    # one copy after it, outside the flush, in launch order
+    launches, waits, copies = (by["repro.fl.flush." + part]
+                               for part in ("launch", "wait", "copy"))
+    assert len(launches) == len(waits) == len(copies) == len(results)
+    for launch, wait, copy in zip(launches, waits, copies):
+        assert launch[2] <= wait[1] and wait[2] <= copy[1]
+        assert launch[3]["program"] == wait[3]["program"] == \
+            "jit__cohort_round"
+        assert wait[3]["ready"] in (0, 1)
+        assert not any(_inside(wait, f) for f in by["repro.fl.flush"])
     # the copy moves exactly the round's result to the host
     assert [s[3]["nbytes"] for s in by["repro.fl.flush.copy"]] == results
     # each fold moves K host-resident updates to the device
